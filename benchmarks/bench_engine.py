@@ -44,9 +44,10 @@ def run(n: int = 1_000_000) -> List[str]:
         t = bench(call, warmup=1, iters=5)
         out.append(row(f"engine_{name}_n{n}", t * 1e6, f"rows_per_s={n / t:.2e}"))
 
-    # Pallas fused kernel (interpret mode on CPU — correctness/structure,
+    # Pallas fused kernel (interpreted on the CPU — correctness/structure,
     # not TPU speed) vs the pure-jnp oracle
     from repro.kernels.fused_filter_agg import fused_filter_agg, fused_filter_agg_ref
+    from repro.runtime.device import pallas_interpret
 
     keys = jnp.asarray(rng.integers(0, 256, 131072).astype(np.int32))
     vals = jnp.asarray(rng.random(131072).astype(np.float32))
@@ -55,7 +56,6 @@ def run(n: int = 1_000_000) -> List[str]:
     def kernel_call():
         s, c = fused_filter_agg(
             keys, vals, filt, op="ge", threshold=0.5, num_groups=256,
-            interpret=True,
         )
         jax.block_until_ready(s)
 
@@ -71,7 +71,7 @@ def run(n: int = 1_000_000) -> List[str]:
         row(
             "kernel_fused_filter_agg_131k",
             tk * 1e6,
-            f"ref_us={tr * 1e6:.0f};interpret_mode=structural_check",
+            f"ref_us={tr * 1e6:.0f};interpreted={pallas_interpret()}",
         )
     )
     return out

@@ -8,9 +8,9 @@ zero registration) introduced with SQL v2:
   route/compile) vs warm, then a kernel-vs-jnp A/B on the exec phase
   (isolated via the ``QueryExecuted`` telemetry breakdown).  Results are
   asserted byte-identical across engines — the kernel route is a perf
-  knob, never a semantics knob.  The kernel runs in Pallas *interpret*
-  mode on CPU (the container has no TPU), so its absolute numbers carry
-  interpreter overhead; the A/B is reported, not asserted.
+  knob, never a semantics knob.  On the CPU the kernel runs in the
+  Pallas interpreter (``runtime/device.py``), so its absolute numbers
+  there carry interpreter overhead.
 * **pooled_scan** — the joined query's table scans with object-store GET
   latency restored (see ``bench_parallel_dag._S3LikeStore``), serial vs
   pooled with kernel-sized work items (``KERNEL_CHUNK_ROWS``).
@@ -35,6 +35,7 @@ import numpy as np
 from benchmarks.bench_parallel_dag import _S3LikeStore
 from benchmarks.common import bench, perf_meta, row
 from repro.api import Client
+from repro.runtime.device import pallas_interpret
 from repro.table import Predicate, TableFormat, execute_scan, plan_scan
 from repro.table.scan import KERNEL_CHUNK_ROWS
 from repro.table.schema import Schema
@@ -118,7 +119,7 @@ def _joined_query(n: int, rng: np.random.Generator) -> Dict:
         "kernel_exec_s": kernel_exec_s,
         "jnp_exec_s": jnp_exec_s,
         "kernel_vs_jnp": jnp_exec_s / max(kernel_exec_s, 1e-9),
-        "interpret_mode": True,
+        "interpret_mode": pallas_interpret(),
         "engines_byte_identical": True,
     }
 
@@ -194,7 +195,8 @@ def run(n: int = 200_000, json_path: Optional[str] = None) -> List[str]:
             f"rows={q['rows']};groups={q['groups']};cold_s={q['cold_s']:.3f};"
             f"kernel_exec_s={q['kernel_exec_s']:.4f};"
             f"jnp_exec_s={q['jnp_exec_s']:.4f};"
-            f"kernel_vs_jnp={q['kernel_vs_jnp']:.2f}x(interpret);"
+            f"kernel_vs_jnp={q['kernel_vs_jnp']:.2f}x;"
+            f"interpreted={q['interpret_mode']};"
             "byte_identical=yes",
         )
     )

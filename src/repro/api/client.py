@@ -51,6 +51,7 @@ from repro.maintenance import (
     compact_table,
     prune_cache,
 )
+from repro.runtime import device
 from repro.runtime.executor import ExecutorConfig, ServerlessExecutor
 from repro.table.format import Snapshot, TableFormat
 from repro.table.schema import Schema
@@ -125,6 +126,9 @@ class Client:
     ):
         if path is None:
             path = tempfile.mkdtemp(prefix="repro_lake_")
+        #: where compiled programs persist across processes (see
+        #: runtime/device.py) — every surface builds a Client first
+        self.compile_cache_dir = device.enable_compile_cache()
         self.path = Path(path)
         self.store = ObjectStore(self.path)
         #: the observability plane: one bus every component publishes

@@ -322,12 +322,12 @@ def _kernel_filter_agg(rel: Columnar, query: Query, route: RouteDecision) -> Col
     if not value_cols:  # COUNT(*)-only (or bare GROUP BY): one zero-value pass
         _, counts_f = fused_filter_agg(
             keys_slot, jnp.zeros((rel.capacity,), jnp.float32), filt,
-            op=op, threshold=thr, num_groups=G, interpret=route.interpret,
+            op=op, threshold=thr, num_groups=G,
         )
     for cname, vals in value_cols.items():
         sums_f, counts_f = fused_filter_agg(
             keys_slot, vals, filt,
-            op=op, threshold=thr, num_groups=G, interpret=route.interpret,
+            op=op, threshold=thr, num_groups=G,
         )
         sums_by_col[cname] = sums_f
 
@@ -431,15 +431,14 @@ def _compiled_for(
     query: Query, group_capacity: Optional[int], route: Optional[RouteDecision]
 ) -> Callable:
     @jax.jit
-    def run(rel: Columnar, joined: Dict[str, Columnar]) -> Columnar:
+    def run(
+        rel: Columnar, joined: Optional[Dict[str, Columnar]] = None
+    ) -> Columnar:
         return execute_query(
             query, rel, group_capacity=group_capacity, joined=joined, route=route
         )
 
-    def call(rel: Columnar, joined: Optional[Dict[str, Columnar]] = None) -> Columnar:
-        return run(rel, joined or {})
-
-    return call
+    return run
 
 
 def compile_query(
@@ -451,6 +450,6 @@ def compile_query(
     """Return the jit-compiled executable for a query (cached — this cache
     is the engine-level face of the runtime's warm-container cache).
 
-    The executable takes ``(rel, joined=None)``; single-table callers keep
-    the old one-argument form."""
+    The executable takes ``(rel, joined=None)``; being a ``jax.jit``
+    function, its ``lower(...)`` gives the program it runs."""
     return _compiled_for(query, group_capacity, route)

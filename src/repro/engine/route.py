@@ -14,8 +14,8 @@ Routing rules (``engine="auto"``):
 * the group key is integer/bool with *known* min/max statistics (shard
   stats folded over the snapshot) spanning at most ``max_groups``
   distinct values — the kernel's dense one-hot group axis must fit VMEM;
-* exactness is provable: the kernel accumulates in f32 (einsum on the
-  MXU), so every aggregated column must be integer/bool with
+* exactness is provable: the kernel accumulates in f32 (adds on the
+  VPU), so every aggregated column must be integer/bool with
   ``max(|min|, |max|) * rows < 2**24`` and the row count itself below
   ``2**24`` — then f32 sums/counts are exact integers and casting back
   reproduces the jnp path's int32 scatter-adds bit-for-bit.  Float
@@ -235,7 +235,6 @@ class RouteDecision:
     num_groups: int = 0
     key_offset: int = 0
     native_filter: bool = False
-    interpret: bool = True
     trace: Optional[RouteTrace] = field(default=None, compare=False, repr=False)
 
     def to_json_dict(self) -> Dict[str, Any]:
@@ -315,7 +314,6 @@ def plan_route(
     stats: Optional[Dict[str, Tuple[int, int]]] = None,
     total_rows: Optional[int] = None,
     max_groups: int = DEFAULT_MAX_GROUPS,
-    interpret: bool = True,
 ) -> RouteDecision:
     """Decide the engine for one query (see module docstring for rules)."""
     if engine not in ("auto", "kernel", "jnp"):
@@ -503,6 +501,5 @@ def plan_route(
         num_groups=num_groups,
         key_offset=kmin,
         native_filter=native,
-        interpret=interpret,
         trace=RouteTrace(tuple(checks)),
     )

@@ -9,16 +9,19 @@ Three kernels, each a `<name>/` subpackage with:
 1. ``fused_filter_agg`` — the paper's 4.4.2 optimization as a single VMEM
    pass: predicate + masked grouped aggregation without materializing the
    filtered intermediate.  TPU adaptation of a row-wise CPU pipeline:
-   one-hot compare against the group lane axis, block-accumulated over a
-   sequential grid (no scatter — dense MXU/VPU-friendly ops).
+   one-hot compare against the group ids laid along sublanes, per-lane
+   partial sums on the VPU, block-accumulated over a sequential grid (no
+   scatter, no MXU pass that would round values to bf16).
 2. ``flash_attention`` — blockwise online-softmax causal attention
    (training + prefill), with optional sliding window (SWA archs).
 3. ``decode_attention`` — single-token attention against a long KV cache,
    S-blocked with running-max/denominator accumulators (serving).
 
-Kernels are validated in interpret mode on CPU (the container has no TPU);
-the pure-JAX reference path remains available everywhere and kernels are
-switchable via config.
+Mosaic compiles the kernels on a TPU; on the CPU, where the tests run,
+they run in the Pallas interpreter.  ``runtime/device.py`` decides which
+from JAX's backend — no caller passes it.  ``tests/test_tpu_compile.py``
+compiles each kernel for a described v5e at real widths, and
+``chip_smoke.py`` runs the query kernel on the chip.
 
 Routing (when does a query actually hit ``fused_filter_agg``?)
 --------------------------------------------------------------

@@ -14,7 +14,7 @@ from repro.kernels.decode_attention.kernel import (
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_s", "interpret")
+    jax.jit, static_argnames=("scale", "block_s")
 )
 def decode_attention(
     q: jax.Array,        # (B, H, D)
@@ -24,7 +24,6 @@ def decode_attention(
     *,
     scale: Optional[float] = None,
     block_s: int = DEFAULT_BLOCK_S,
-    interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
     hkv, s = k_cache.shape[1], k_cache.shape[2]
@@ -32,7 +31,7 @@ def decode_attention(
     group = h // hkv
     scale = scale if scale is not None else 1.0 / (d**0.5)
     bs = min(block_s, s)
-    lengths_bh = jnp.broadcast_to(lengths[:, None], (b, h)).reshape(b * h, 1)
+    lengths_bh = jnp.broadcast_to(lengths[:, None], (b, h)).reshape(b * h)
     out = decode_attention_kernel(
         q.reshape(b * h, 1, d),
         k_cache.reshape(b * hkv, s, d),
@@ -41,6 +40,5 @@ def decode_attention(
         group=group,
         scale=scale,
         block_s=bs,
-        interpret=interpret,
     )
     return out.reshape(b, h, d).astype(q.dtype)

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import device
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
@@ -107,7 +109,6 @@ def flash_attention_kernel(
     window: Optional[int],
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
 ) -> jax.Array:
     bh, s, d = q.shape
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
@@ -130,5 +131,5 @@ def flash_attention_kernel(
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        interpret=interpret,
+        interpret=device.pallas_interpret(),
     )(q, k, v)

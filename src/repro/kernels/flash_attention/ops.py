@@ -16,7 +16,7 @@ from repro.kernels.flash_attention.kernel import (
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "scale", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "window", "scale", "block_q", "block_k"),
 )
 def flash_attention(
     q: jax.Array,  # (B, H, S, D)
@@ -28,7 +28,6 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
 ) -> jax.Array:
     b, h, s, d = q.shape
     hkv = k.shape[1]
@@ -47,6 +46,5 @@ def flash_attention(
         window=window,
         block_q=bq,
         block_k=bk,
-        interpret=interpret,
     )
     return out.reshape(b, h, s, d)

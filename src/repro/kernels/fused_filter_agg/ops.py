@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.fused_filter_agg.kernel import (
     DEFAULT_BLOCK_ROWS,
+    GROUP_ALIGN,
     fused_filter_agg_kernel,
 )
 
@@ -17,7 +18,7 @@ _LANES = 128
 
 @functools.partial(
     jax.jit,
-    static_argnames=("op", "threshold", "num_groups", "block_rows", "interpret"),
+    static_argnames=("op", "threshold", "num_groups", "block_rows"),
 )
 def fused_filter_agg(
     keys: jax.Array,        # int32[n]
@@ -28,17 +29,17 @@ def fused_filter_agg(
     threshold: float = 0.0,
     num_groups: int = 256,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Grouped (sum, count) over rows passing the predicate — one fused pass.
 
     Pads the row stream to a whole number of (block_rows × 128) tiles and
-    lane-aligns the group axis; padded rows carry key ``-1`` (matches no
-    group) so they contribute nothing.
+    the group axis to whole sublanes; padded rows carry key ``-1`` (matches
+    no group) so they contribute nothing.  The kernel's per-lane partials
+    are folded here, in f32: exact for integer values whose sums stay
+    below 2**24.
     """
     n = keys.shape[0]
-    g_pad = -num_groups % _LANES
-    num_groups_padded = num_groups + g_pad
+    num_groups_padded = num_groups + (-num_groups % GROUP_ALIGN)
     tile = block_rows * _LANES
     n_pad = -n % tile
     keys_p = jnp.pad(keys.astype(jnp.int32), (0, n_pad), constant_values=-1)
@@ -53,6 +54,8 @@ def fused_filter_agg(
         threshold=threshold,
         num_groups=num_groups_padded,
         block_rows=block_rows,
-        interpret=interpret,
     )
-    return sums[:num_groups], counts[:num_groups]
+    return (
+        sums.sum(axis=1)[:num_groups],
+        counts.sum(axis=1)[:num_groups],
+    )

@@ -197,9 +197,7 @@ def attend_train(
     if cfg.use_flash_kernel:
         from repro.kernels.flash_attention import flash_attention
 
-        out = flash_attention(
-            q, k, v, causal=True, window=cfg.window, interpret=True
-        )
+        out = flash_attention(q, k, v, causal=True, window=cfg.window)
     else:
         out = _attend_full(q, k, v, cfg)
     b, h, s, d = out.shape
@@ -238,7 +236,7 @@ def prefill(
     if cfg.use_flash_kernel:
         from repro.kernels.flash_attention import flash_attention
 
-        out = flash_attention(q, k, v, causal=True, window=cfg.window, interpret=True)
+        out = flash_attention(q, k, v, causal=True, window=cfg.window)
     else:
         out = _attend_full(q, k, v, cfg)
     b, h, _, d = out.shape
@@ -272,7 +270,7 @@ def decode_step(
         from repro.kernels.decode_attention import decode_attention
 
         out = decode_attention(
-            q[:, :, 0], k_cache, v_cache, new_lengths, interpret=True
+            q[:, :, 0], k_cache, v_cache, new_lengths
         )  # (B, H, D)
         out = out.reshape(b, 1, cfg.n_heads * cfg.d_head)
     else:

@@ -43,9 +43,7 @@ def pytest_configure(config):
 
 def pytest_collection_modifyitems(config, items):
     """Auto-mark property-based tests as slow so `-m 'not slow'` gives a
-    quick signal pass.  Real hypothesis sets ``fn.hypothesis``; the offline
-    fallback (tests/_hypothesis_compat.py) sets ``fn._property_test``."""
+    quick signal pass (hypothesis marks its tests with ``fn.hypothesis``)."""
     for item in items:
-        fn = getattr(item, "function", None)
-        if hasattr(fn, "hypothesis") or getattr(fn, "_property_test", False):
+        if hasattr(getattr(item, "function", None), "hypothesis"):
             item.add_marker(pytest.mark.slow)

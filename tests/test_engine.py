@@ -3,12 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # offline CI: deterministic fallback shim
-    from tests._hypothesis_compat import given, settings
-    from tests._hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.engine import Columnar, Query, col, compile_query, execute_query, parse_sql
 

@@ -125,7 +125,6 @@ def test_route_decision_equality_and_hash_ignore_trace():
         num_groups=r.num_groups,
         key_offset=r.key_offset,
         native_filter=r.native_filter,
-        interpret=r.interpret,
     )
     assert r.trace is not None and bare.trace is None
     assert r == bare

@@ -28,14 +28,14 @@ TOL = dict(rtol=2e-3, atol=2e-3)
 )
 def test_flash_causal_shapes(b, h, hkv, s, d, rng):
     q, k, v = qkv(rng, b, h, hkv, s, d)
-    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64, interpret=True)
+    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
     exp = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
 
 
 def test_flash_noncausal(rng):
     q, k, v = qkv(rng, 1, 2, 2, 128, 32)
-    got = flash_attention(q, k, v, causal=False, block_q=64, block_k=64, interpret=True)
+    got = flash_attention(q, k, v, causal=False, block_q=64, block_k=64)
     exp = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
 
@@ -44,7 +44,7 @@ def test_flash_noncausal(rng):
 def test_flash_sliding_window(window, rng):
     q, k, v = qkv(rng, 1, 2, 1, 256, 32)
     got = flash_attention(
-        q, k, v, causal=True, window=window, block_q=64, block_k=64, interpret=True
+        q, k, v, causal=True, window=window, block_q=64, block_k=64
     )
     exp = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
@@ -53,7 +53,7 @@ def test_flash_sliding_window(window, rng):
 def test_flash_bf16(rng):
     q, k, v = qkv(rng, 1, 2, 2, 128, 32, dtype=np.float32)
     q, k, v = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
-    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64, interpret=True)
+    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
     exp = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(exp, np.float32), rtol=3e-2, atol=3e-2
@@ -63,8 +63,8 @@ def test_flash_bf16(rng):
 def test_flash_block_shape_independence(rng):
     """Block size must not change the math."""
     q, k, v = qkv(rng, 1, 2, 2, 256, 32)
-    a = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-    b = flash_attention(q, k, v, block_q=128, block_k=32, interpret=True)
+    a = flash_attention(q, k, v, block_q=64, block_k=64)
+    b = flash_attention(q, k, v, block_q=128, block_k=32)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
 
 
@@ -82,7 +82,7 @@ def test_decode_shapes(b, h, hkv, s, d, rng):
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     lengths = jnp.asarray(rng.integers(1, s + 1, b).astype(np.int32))
-    got = decode_attention(q, k, v, lengths, block_s=128, interpret=True)
+    got = decode_attention(q, k, v, lengths, block_s=128)
     exp = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
 
@@ -93,7 +93,7 @@ def test_decode_full_cache(rng):
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     lengths = jnp.full((b,), s, jnp.int32)
-    got = decode_attention(q, k, v, lengths, block_s=64, interpret=True)
+    got = decode_attention(q, k, v, lengths, block_s=64)
     exp = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
 
@@ -105,7 +105,7 @@ def test_decode_tiny_length(rng):
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     lengths = jnp.ones((b,), jnp.int32)
-    got = decode_attention(q, k, v, lengths, block_s=64, interpret=True)
+    got = decode_attention(q, k, v, lengths, block_s=64)
     exp = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), **TOL)
     # attending to 1 token == that token's value
@@ -120,7 +120,7 @@ def test_decode_bf16(rng):
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d))).astype(jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d))).astype(jnp.bfloat16)
     lengths = jnp.full((b,), s, jnp.int32)
-    got = decode_attention(q, k, v, lengths, block_s=128, interpret=True)
+    got = decode_attention(q, k, v, lengths, block_s=128)
     exp = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(exp, np.float32), rtol=3e-2, atol=3e-2

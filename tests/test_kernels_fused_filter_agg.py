@@ -2,12 +2,8 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # offline CI: deterministic fallback shim
-    from tests._hypothesis_compat import given, settings
-    from tests._hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.fused_filter_agg import fused_filter_agg, fused_filter_agg_ref
 
@@ -26,7 +22,7 @@ def test_shapes_sweep(n, num_groups, rng):
     keys, vals, filt = make_inputs(n, num_groups, rng)
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op="ge", threshold=50.0, num_groups=num_groups, interpret=True,
+        op="ge", threshold=50.0, num_groups=num_groups,
     )
     exp_s, exp_c = fused_filter_agg_ref(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
@@ -42,7 +38,7 @@ def test_ops_sweep(op, rng):
     filt = np.round(filt)  # make eq/ne meaningful
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op=op, threshold=42.0, num_groups=128, interpret=True,
+        op=op, threshold=42.0, num_groups=128,
     )
     exp_s, exp_c = fused_filter_agg_ref(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
@@ -59,7 +55,7 @@ def test_dtypes_sweep(dtype, rng):
     filt = rng.integers(0, 10, 1024).astype(np.float32)
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op="gt", threshold=4.0, num_groups=64, interpret=True,
+        op="gt", threshold=4.0, num_groups=64,
     )
     exp_s, exp_c = fused_filter_agg_ref(
         jnp.asarray(keys), jnp.asarray(vals).astype(jnp.float32), jnp.asarray(filt),
@@ -73,7 +69,7 @@ def test_empty_selection(rng):
     keys, vals, filt = make_inputs(512, 128, rng)
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op="ge", threshold=1e9, num_groups=128, interpret=True,
+        op="ge", threshold=1e9, num_groups=128,
     )
     assert np.asarray(got_s).sum() == 0 and np.asarray(got_c).sum() == 0
 
@@ -88,7 +84,7 @@ def test_matches_query_engine_groupby(rng):
     eng = execute_query(q, rel).to_numpy()
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op="ge", threshold=50.0, num_groups=32, interpret=True,
+        op="ge", threshold=50.0, num_groups=32,
     )
     got_s, got_c = np.asarray(got_s), np.asarray(got_c)
     for i, key in enumerate(eng["k"]):
@@ -110,7 +106,7 @@ def test_property_kernel_equals_oracle(n, g, threshold, seed):
     filt = rng.standard_normal(n).astype(np.float32)
     got_s, got_c = fused_filter_agg(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),
-        op="lt", threshold=threshold, num_groups=g, interpret=True,
+        op="lt", threshold=threshold, num_groups=g,
     )
     exp_s, exp_c = fused_filter_agg_ref(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(filt),

@@ -1,12 +1,8 @@
 """Unit + property tests for the content-addressed object store."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # offline CI: deterministic fallback shim
-    from tests._hypothesis_compat import given, settings
-    from tests._hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.io import ObjectStore, array_to_bytes, bytes_to_array
 

@@ -3,12 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # offline CI: deterministic fallback shim
-    from tests._hypothesis_compat import given, settings
-    from tests._hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.distribution.sharding import DEFAULT_RULES

@@ -1,12 +1,8 @@
 """TensorTable format: snapshots, sharding, stats, scan pruning."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # offline CI: deterministic fallback shim
-    from tests._hypothesis_compat import given, settings
-    from tests._hypothesis_compat import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.io import ObjectStore
 from repro.table import Predicate, Schema, TableFormat, execute_scan, plan_scan
