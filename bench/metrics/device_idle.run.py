@@ -1,0 +1,6 @@
+"""Share of the traced window of a pipeline cell in which no operation ran
+on the device."""
+
+
+def read(run):
+    return run.idle_percent() if run.kind == "run" else None

@@ -1,0 +1,11 @@
+"""The benchmark's tests run on the CPU, Pallas interpreted, at tiny sizes.
+
+    python -m pytest bench/tests
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+BENCH = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
