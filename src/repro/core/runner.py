@@ -45,12 +45,14 @@ stage-id order so branch history stays linear and deterministic.
 from __future__ import annotations
 
 import heapq
+import itertools
 import threading
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.catalog.nessie import Catalog, CatalogError
@@ -73,6 +75,7 @@ from repro.core.snapshot import (
 )
 from repro.engine.columnar import Columnar
 from repro.runtime.executor import ServerlessExecutor
+from repro.runtime.phases import Phases
 from repro.table.format import Snapshot, TableFormat
 from repro.table.scan import execute_scan
 from repro.table.schema import Column, Schema
@@ -100,6 +103,9 @@ log = get_logger("core.runner")
 #: drops its own trace (a 1000-stage, 50-shard-per-stage run is ~55k
 #: events); the bound still protects a pathological publisher
 _RUNLOG_BUFFER = 131072
+
+#: process-unique ids of interactive queries (``QueryExecuted.query_id``)
+_query_ids = itertools.count(1)
 
 
 class ExpectationFailed(RuntimeError):
@@ -252,50 +258,61 @@ class Runner:
         from repro.engine.sql import parse_sql
         from repro.table.scan import KERNEL_CHUNK_ROWS
 
+        query_id = next(_query_ids)
+        phases = Phases("repro.query")
         t0 = time.perf_counter()
-        query = parse_sql(sql)
-        text = query.raw_sql or sql
-        parse_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("repro.query", query_id=query_id):
+            with phases("parse"):
+                query = parse_sql(sql)
+                text = query.raw_sql or sql
 
-        # -- zero-registration name resolution + planning ----------------
-        # (shared with `repro explain` — the static route verdict agrees
-        # with this decision because it IS this decision)
-        t1 = time.perf_counter()
-        snapshots = resolve_query_snapshots(
-            self.catalog, self.fmt, query,
-            branch=branch, commit_id=commit_id, text=text,
-        )
-        _check_query_columns(query, snapshots, text)
-        iq = plan_interactive_query(query, snapshots, engine=engine)
-        route, residual, scans = iq.route, iq.residual, iq.scans
-        plan_s = time.perf_counter() - t1
-
-        # -- pooled parallel scans, kernel-sized chunks -------------------
-        # tables scan one after another; each scan parallelizes its own
-        # shards on the io pool (nesting table-level fan-out on the same
-        # pool could deadlock it)
-        t2 = time.perf_counter()
-        rels = {
-            table: Columnar.from_numpy(
-                execute_scan(
-                    self.fmt, scan, pool=self.executor.io_pool,
-                    bus=self.bus, tags={"source": "query", "table": table},
-                    chunk_rows=KERNEL_CHUNK_ROWS,
+            # -- zero-registration name resolution + planning ------------
+            # (shared with `repro explain` — the static route verdict
+            # agrees with this decision because it IS this decision)
+            with phases("plan"):
+                snapshots = resolve_query_snapshots(
+                    self.catalog, self.fmt, query,
+                    branch=branch, commit_id=commit_id, text=text,
                 )
-            )
-            for table, scan in scans.items()
-        }
-        scan_s = time.perf_counter() - t2
+                _check_query_columns(query, snapshots, text)
+                iq = plan_interactive_query(query, snapshots, engine=engine)
+            route, residual, scans = iq.route, iq.residual, iq.scans
 
-        # -- one compiled program (jnp or fused-kernel path) --------------
-        t3 = time.perf_counter()
-        residual_query = _replace(query, filter_expr=residual)
-        joined = {j.table: rels[j.table] for j in query.joins}
-        out = compile_query(residual_query, route=route)(
-            rels[query.source], joined or None
-        )
-        result = out.to_numpy()
-        exec_s = time.perf_counter() - t3
+            # -- pooled parallel scans, kernel-sized chunks ---------------
+            # tables scan one after another; each scan parallelizes its
+            # own shards on the io pool (nesting table-level fan-out on
+            # the same pool could deadlock it).  The copy to the device
+            # is enqueued here and awaited at the start of execution.
+            t2 = time.perf_counter()
+            with phases("read"):
+                data = {
+                    table: execute_scan(
+                        self.fmt, scan, pool=self.executor.io_pool,
+                        bus=self.bus,
+                        tags={"source": "query", "table": table,
+                              "query_id": query_id},
+                        chunk_rows=KERNEL_CHUNK_ROWS,
+                    )
+                    for table, scan in scans.items()
+                }
+            with phases("copy"):
+                rels = {t: Columnar.from_numpy(d) for t, d in data.items()}
+            scan_s = time.perf_counter() - t2
+
+            # -- one compiled program (jnp or fused-kernel path) ----------
+            t3 = time.perf_counter()
+            with phases("copy"):
+                jax.block_until_ready(rels)
+            residual_query = _replace(query, filter_expr=residual)
+            joined = {j.table: rels[j.table] for j in query.joins}
+            program = compile_query(residual_query, route=route)
+            with phases("device"):
+                out = jax.block_until_ready(
+                    program(rels[query.source], joined or None)
+                )
+            with phases("fetch"):
+                result = out.to_numpy()
+            exec_s = time.perf_counter() - t3
 
         rows_out = len(next(iter(result.values()))) if result else 0
         self._publish(QueryExecuted(
@@ -304,10 +321,16 @@ class Runner:
             shards_read=sum(len(s.shards) for s in scans.values()),
             wall_s=time.perf_counter() - t0,
             engine_path=route.engine_path,
-            parse_s=parse_s,
-            plan_s=plan_s,
+            parse_s=phases["parse"],
+            plan_s=phases["plan"],
             scan_s=scan_s,
             exec_s=exec_s,
+            read_s=phases["read"],
+            copy_s=phases["copy"],
+            device_s=phases["device"],
+            fetch_s=phases["fetch"],
+            compiles=phases.compiles,
+            query_id=query_id,
         ))
         return result
 
@@ -746,12 +769,15 @@ class Runner:
                 sid = next_commit[0]
                 updates = pending_commits.pop(sid)
                 t0 = time.perf_counter()
-                if updates:
-                    self.catalog.commit(
-                        ephemeral, updates,
-                        message=f"run {run_id} stage {sid}",
-                        author="runner",
-                    )
+                with jax.profiler.TraceAnnotation(
+                    "repro.stage.commit", run_id=run_id, stage_id=sid
+                ):
+                    if updates:
+                        self.catalog.commit(
+                            ephemeral, updates,
+                            message=f"run {run_id} stage {sid}",
+                            author="runner",
+                        )
                 commit_s = time.perf_counter() - t0
                 stage_timings.setdefault(sid, {})["commit_s"] = commit_s
                 self._publish(StageCommitted(
@@ -765,66 +791,88 @@ class Runner:
             queue_s = t_exec - queued_at.get(stage.stage_id, t_exec)
             self._publish(StageStarted(run_id=run_id, stage_id=stage.stage_id))
             scan_tags = {"run_id": run_id, "stage_id": stage.stage_id}
-            inputs: List[Columnar] = []
-            for table in sorted(stage.scans):
-                # streaming mode drives the scan through the incremental
-                # shard iterator (bounded read-ahead window) — chunking and
-                # shard order are shared with the barrier path, so the
-                # concatenated input is byte-identical either way
-                data = execute_scan(
-                    self.fmt, stage.scans[table].plan,
-                    pool=self.executor.io_pool,
-                    bus=self.bus, tags=dict(scan_tags, table=table),
-                    streaming=use_streaming,
+            phases = Phases("repro.stage")
+            with jax.profiler.TraceAnnotation(
+                "repro.stage", run_id=run_id, stage_id=stage.stage_id
+            ):
+                with phases("read"):
+                    # streaming mode drives the scan through the
+                    # incremental shard iterator (bounded read-ahead
+                    # window) — chunking and shard order are shared with
+                    # the barrier path, so the concatenated input is
+                    # byte-identical either way
+                    data = [
+                        execute_scan(
+                            self.fmt, stage.scans[table].plan,
+                            pool=self.executor.io_pool,
+                            bus=self.bus, tags=dict(scan_tags, table=table),
+                            streaming=use_streaming,
+                        )
+                        for table in sorted(stage.scans)
+                    ]
+                    internal: List[Any] = []
+                    for name in stage.internal_inputs:
+                        with state_lock:  # data locality: in-memory artifact
+                            rel = env.get(name)
+                        if rel is None:  # fallback: the ephemeral branch
+                            key = self.catalog.table_key(name, branch=ephemeral)
+                            rel = self.fmt.read(self.fmt.load_snapshot(key))
+                        internal.append(rel)
+                with phases("copy"):
+                    inputs = [Columnar.from_numpy(d) for d in data] + [
+                        rel if isinstance(rel, Columnar)
+                        else Columnar.from_numpy(rel)
+                        for rel in internal
+                    ]
+                    jax.block_until_ready(inputs)
+                # one construction site (physical.stage_function_spec) for
+                # the dispatch spec — the scheduler's cost lookup and the
+                # executor's latency history key the same fingerprint by
+                # definition.  The executor times the compile and the
+                # device program on its container thread.
+                spec = stage_function_spec(pipeline.name, stage)
+                (outputs, stage_checks), attempt = self.executor.run_recorded(
+                    spec, *inputs, tags=scan_tags
                 )
-                inputs.append(Columnar.from_numpy(data))
-            for name in stage.internal_inputs:
-                with state_lock:  # data locality: reuse in-memory artifact
-                    rel = env.get(name)
-                if rel is None:  # fallback: read from the ephemeral branch
-                    key = self.catalog.table_key(name, branch=ephemeral)
-                    rel = Columnar.from_numpy(
-                        self.fmt.read(self.fmt.load_snapshot(key))
-                    )
-                inputs.append(rel)
-            # one construction site (physical.stage_function_spec) for the
-            # dispatch spec — the scheduler's cost lookup and the executor's
-            # latency history key the same fingerprint by definition
-            spec = stage_function_spec(pipeline.name, stage)
-            outputs, stage_checks = self.executor.run(
-                spec, *inputs, tags=scan_tags
-            )
-            if use_streaming:
-                # streaming handoff: publish in-memory outputs and unblock
-                # dependent stages NOW, before artifact writes land —
-                # downstream stages consume completed upstream results
-                # while this stage's store I/O is still in flight.  The
-                # stage barrier is retained where it matters: audits and
-                # catalog commits still drain in stage-id order below.
-                with state_lock:
-                    for name, rel in outputs.items():
-                        env[name] = rel
-                outputs_ready(stage.stage_id)
-            # store I/O (artifact writes) runs outside the state lock so
-            # concurrent stages overlap their writes; only the publication
-            # of results + the ordered commit drain is serialized
-            updates: Dict[str, Optional[str]] = {}
-            node_bytes: Dict[str, int] = {}
-            written: Dict[str, Any] = {}
-            for name, rel in outputs.items():
-                compact = rel.to_numpy(compact=True)
-                node_bytes[name] = sum(arr.nbytes for arr in compact.values())
-                schema = Schema(
-                    tuple(
-                        Column(c, str(compact[c].dtype)) for c in sorted(compact)
-                    )
-                )
-                snap = self.fmt.write(name, schema, compact)
-                key = self.fmt.manifest_key(snap)
-                updates[name] = key
-                written[name] = (rel, key)
-            now = time.time()
-            exec_s = time.perf_counter() - t_exec
+                if use_streaming:
+                    # streaming handoff: publish in-memory outputs and
+                    # unblock dependent stages NOW, before artifact writes
+                    # land — downstream stages consume completed upstream
+                    # results while this stage's store I/O is still in
+                    # flight.  The stage barrier is retained where it
+                    # matters: audits and catalog commits still drain in
+                    # stage-id order below.
+                    with state_lock:
+                        for name, rel in outputs.items():
+                            env[name] = rel
+                    outputs_ready(stage.stage_id)
+                # store I/O (artifact writes) runs outside the state lock
+                # so concurrent stages overlap their writes; only the
+                # publication of results + the ordered commit drain is
+                # serialized
+                with phases("fetch"):
+                    fetched = {
+                        name: rel.to_numpy(compact=True)
+                        for name, rel in outputs.items()
+                    }
+                updates: Dict[str, Optional[str]] = {}
+                node_bytes: Dict[str, int] = {}
+                written: Dict[str, Any] = {}
+                with phases("write"):
+                    for name, compact in fetched.items():
+                        node_bytes[name] = sum(
+                            arr.nbytes for arr in compact.values()
+                        )
+                        schema = Schema(tuple(
+                            Column(c, str(compact[c].dtype))
+                            for c in sorted(compact)
+                        ))
+                        snap = self.fmt.write(name, schema, compact)
+                        key = self.fmt.manifest_key(snap)
+                        updates[name] = key
+                        written[name] = (outputs[name], key)
+                now = time.time()
+                exec_s = time.perf_counter() - t_exec
             # predicted-vs-actual: the scheduling estimate against the full
             # driver span (scan → execute → write) — persisted to the
             # latencyhist namespace alongside the self-correcting medians
@@ -834,6 +882,10 @@ class Runner:
             self._publish(StageFinished(
                 run_id=run_id, stage_id=stage.stage_id, exec_s=exec_s,
                 outputs=sorted(outputs), checks=sorted(stage_checks),
+                read_s=phases["read"], copy_s=phases["copy"],
+                compile_s=attempt.compile_s, device_s=attempt.duration_s,
+                fetch_s=phases["fetch"], write_s=phases["write"],
+                compiles=phases.compiles + attempt.compiles,
             ))
             with state_lock:
                 counters["stages_executed"] += 1

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.engine.columnar import Columnar
 from repro.engine.query import Agg, Query
 from repro.engine.route import RouteDecision, native_filter_of
+from repro.runtime.phases import named
 
 def apply_filter(rel: Columnar, query: Query) -> Columnar:
     if query.filter_expr is None:
@@ -426,11 +427,24 @@ def execute_query(
     return rel
 
 
+def program_name(query: Query) -> str:
+    """The name of a query's compiled program (``jit_<name>`` in a device
+    trace): its tables, group keys, aggregate functions and filtered
+    columns.  Literals are left out, so statements that differ only in
+    them share one name."""
+    parts = [query.source, *(j.table for j in query.joins)]
+    if query.group_keys:
+        parts += ["by", *query.group_keys]
+    parts += [agg.fn for agg in query.aggregates]
+    if query.filter_expr is not None:
+        parts += ["where", *sorted(set(query.filter_expr.referenced_columns()))]
+    return "_".join(parts)
+
+
 @functools.lru_cache(maxsize=512)
 def _compiled_for(
     query: Query, group_capacity: Optional[int], route: Optional[RouteDecision]
 ) -> Callable:
-    @jax.jit
     def run(
         rel: Columnar, joined: Optional[Dict[str, Columnar]] = None
     ) -> Columnar:
@@ -438,7 +452,7 @@ def _compiled_for(
             query, rel, group_capacity=group_capacity, joined=joined, route=route
         )
 
-    return run
+    return jax.jit(named(run, program_name(query)))
 
 
 def compile_query(

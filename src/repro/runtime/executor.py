@@ -21,14 +21,16 @@ to a real deployment are what matter and are what the tests pin down:
 """
 from __future__ import annotations
 
-import queue
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.runtime.function import FunctionSpec
+from repro.runtime.phases import Phases
 from repro.runtime.warm import WarmFunctionCache
 from repro.utils.logging import get_logger
 
@@ -77,7 +79,14 @@ class TaskRecord:
     name: str
     attempts: int = 0
     speculated: bool = False
+    #: seconds from the call of the compiled function until its outputs
+    #: are ready on the device; the compile is not in it
     duration_s: float = 0.0
+    #: seconds the attempt spent getting its executable (compiling, or a
+    #: warm-cache lookup)
+    compile_s: float = 0.0
+    #: programs the attempt handed to XLA
+    compiles: int = 0
     worker: str = ""
 
 
@@ -145,7 +154,6 @@ class ServerlessExecutor:
         #: ``max_concurrent_stages`` because the lane only provides threads —
         #: the wave scheduler enforces the actual in-flight bound.
         self._stage_pool: Optional[ThreadPoolExecutor] = None
-        self._durations: List[float] = []
         self._speculations = 0  # duplicates launched, lifetime of the pool
         #: function fingerprint -> recent completed durations (the prior-run
         #: baseline for single-task speculation AND the scheduler's cost
@@ -173,15 +181,13 @@ class ServerlessExecutor:
         self.shutdown()
 
     # ------------------------------------------------------------- running
-    def _attempt(self, spec: FunctionSpec, args: Tuple[Any, ...]) -> Any:
-        if self.fault_injector is not None:
-            self.fault_injector.maybe_fail(spec.name)
-        fn = self.warm_cache.get_or_compile(spec, *args)
-        return fn(*args)
-
     def _run_with_retries(
         self, spec: FunctionSpec, args: Tuple[Any, ...], speculated: bool = False
-    ) -> Any:
+    ) -> Tuple[Any, TaskRecord]:
+        """Run ``spec`` until an attempt succeeds; the result and the
+        record of the attempts.  A successful attempt's ``duration_s``
+        waits for the device and leaves the compile out: it is what the
+        latency history, the cost model and speculation compare."""
         record = TaskRecord(
             name=spec.name,
             speculated=speculated,
@@ -190,13 +196,19 @@ class ServerlessExecutor:
         last_err: Optional[BaseException] = None
         for attempt in range(self.config.max_retries + 1):
             record.attempts = attempt + 1
-            t0 = time.perf_counter()
             try:
-                result = self._attempt(spec, args)
-                record.duration_s = time.perf_counter() - t0
+                if self.fault_injector is not None:
+                    self.fault_injector.maybe_fail(spec.name)
+                phases = Phases("repro.stage")
+                with phases("compile"):
+                    fn = self.warm_cache.get_or_compile(spec, *args)
+                with phases("device"):
+                    result = jax.block_until_ready(fn(*args))
+                record.compile_s = phases["compile"]
+                record.duration_s = phases["device"]
+                record.compiles = phases.compiles
                 with self._lock:
                     self.records.append(record)
-                    self._durations.append(record.duration_s)
                     history = self._latency_history.setdefault(
                         spec.fingerprint, []
                     )
@@ -208,7 +220,7 @@ class ServerlessExecutor:
                     self.metrics.histogram(
                         "executor.task_duration_s"
                     ).observe(record.duration_s)
-                return result
+                return result, record
             except Exception as e:  # container crash → retry
                 last_err = e
                 log.warning(
@@ -225,9 +237,6 @@ class ServerlessExecutor:
         raise TaskFailure(
             f"task {spec.name!r} failed after {self.config.max_retries + 1} attempts"
         ) from last_err
-
-    def submit(self, spec: FunctionSpec, *args: Any) -> "Future[Any]":
-        return self._pool.submit(self._run_with_retries, spec, args)
 
     def submit_stage(self, fn: Callable[..., Any], *args: Any) -> "Future[Any]":
         """Submit one stage *driver* (scan → execute → write) to the stage
@@ -342,8 +351,26 @@ class ServerlessExecutor:
         self, spec: FunctionSpec, *args: Any,
         tags: Optional[Dict[str, Any]] = None,
     ) -> "Future[Any]":
-        """Future-returning ``run()``: primary submitted now, straggler
-        backup armed against the per-fingerprint latency history.
+        """Future-returning ``run()`` (see ``_race``)."""
+        out: "Future[Any]" = Future()
+
+        def unwrap(raced: "Future[Tuple[Any, TaskRecord]]") -> None:
+            err = raced.exception()
+            if err is not None:
+                out.set_exception(err)
+            else:
+                out.set_result(raced.result()[0])
+
+        self._race(spec, args, tags).add_done_callback(unwrap)
+        return out
+
+    def _race(
+        self, spec: FunctionSpec, args: Tuple[Any, ...],
+        tags: Optional[Dict[str, Any]],
+    ) -> "Future[Tuple[Any, TaskRecord]]":
+        """The result and record of the first attempt to succeed: primary
+        submitted now, straggler backup armed against the per-fingerprint
+        latency history.
 
         A single task has no completed siblings to take a median over, so
         the straggler baseline is the latency history of prior runs: once
@@ -442,7 +469,15 @@ class ServerlessExecutor:
     ) -> Any:
         """Run one task synchronously, speculating against its own history
         (blocking face of ``submit_speculative``)."""
-        return self.submit_speculative(spec, *args, tags=tags).result()
+        return self.run_recorded(spec, *args, tags=tags)[0]
+
+    def run_recorded(
+        self, spec: FunctionSpec, *args: Any,
+        tags: Optional[Dict[str, Any]] = None,
+    ) -> Tuple[Any, TaskRecord]:
+        """``run``, with the record of the attempt that won: its device
+        and compile seconds and the programs it handed to XLA."""
+        return self._race(spec, args, tags).result()
 
     # -------------------------------------------------- bulk + speculation
     def map_with_speculation(
@@ -493,7 +528,7 @@ class ServerlessExecutor:
                     (f for f in finished if f.exception() is None), None
                 )
                 if success is not None:
-                    results[i] = success.result()
+                    results[i] = success.result()[0]
                     done[i] = True
                     finish[i] = time.perf_counter()
                     continue

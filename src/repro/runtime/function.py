@@ -10,6 +10,7 @@ compiled executable; anything else is a cold start.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional
 
@@ -27,8 +28,10 @@ class FunctionSpec:
     #: retried/speculated like any other task)
     jit: bool = True
 
-    @property
+    @functools.cached_property
     def fingerprint(self) -> str:
+        # computed once per spec: hashing the source takes milliseconds,
+        # and a dispatch reads the fingerprint several times
         return stable_hash(
             {
                 "name": self.name,

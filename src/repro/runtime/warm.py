@@ -6,7 +6,8 @@ compilation is the cold start; re-invoking a cached executable for the
 same (fingerprint, abstract shapes) is the warm start.  We make the split
 explicit with ``.lower().compile()`` so both phases are measurable —
 benchmarks/bench_serverless.py reports the cold:warm ratio next to the
-paper's claim.
+paper's claim.  The compiled program carries the spec's name
+(``jit_<pipeline>_stage<n>`` for a pipeline stage).
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 
 from repro.runtime.function import FunctionSpec
+from repro.runtime.phases import named
 from repro.utils.hashing import stable_hash
 from repro.utils.logging import get_logger
 
@@ -72,7 +74,7 @@ class WarmFunctionCache:
             else l,
             example_inputs,
         )
-        compiled = jax.jit(spec.fn).lower(*abstract).compile()
+        compiled = jax.jit(named(spec.fn, spec.name)).lower(*abstract).compile()
         dt = time.perf_counter() - t0
         with self._lock:
             self._cache[key] = compiled

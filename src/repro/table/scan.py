@@ -206,7 +206,8 @@ def execute_scan(
 
     ``bus`` (a :class:`repro.telemetry.bus.EventBus`) gets one
     ``ScanShardRead`` per shard; ``tags`` attributes the events to a run
-    (``run_id``/``stage_id``/``table``/``source``) since the scan pool
+    or a query (``run_id``/``stage_id``/``query_id``/``table``/``source``)
+    since the scan pool
     itself has no run context.
 
     ``streaming=True`` drives the same chunks through the incremental
@@ -309,6 +310,7 @@ def _iter_chunk_parts(
                 dur_s=time.perf_counter() - t0,
                 source=tags.get("source", "stage"),
                 stage_id=tags.get("stage_id"),
+                query_id=tags.get("query_id"),
             ))
         return part
 

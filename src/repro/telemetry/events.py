@@ -145,13 +145,27 @@ class StageStarted(Event):
 
 @dataclass
 class StageFinished(Event):
-    """The stage driver finished scan → execute → write (commit pending)."""
+    """The stage finished scan → execute → write (commit pending).
+
+    ``exec_s`` is the stage's span; the phases inside it, in order:
+    ``read_s`` (shard reads, host filter, concat), ``copy_s`` (inputs to
+    the device, until ready), ``compile_s`` (the executor getting the
+    stage's executable), ``device_s`` (its call until the outputs are
+    ready), ``fetch_s`` (outputs back, compacted), ``write_s`` (artifact
+    writes).  ``compiles`` counts the programs the stage handed to XLA."""
 
     kind: ClassVar[str] = "StageFinished"
     stage_id: int = 0
     exec_s: float = 0.0
     outputs: List[str] = field(default_factory=list)
     checks: List[str] = field(default_factory=list)
+    read_s: float = 0.0
+    copy_s: float = 0.0
+    compile_s: float = 0.0
+    device_s: float = 0.0
+    fetch_s: float = 0.0
+    write_s: float = 0.0
+    compiles: int = 0
 
 
 @dataclass
@@ -246,6 +260,8 @@ class ScanShardRead(Event):
     #: "stage" for pipeline scans, "query" for interactive client.query()
     source: str = "stage"
     stage_id: Optional[int] = None
+    #: the interactive query that read the shard (``QueryExecuted.query_id``)
+    query_id: Optional[int] = None
 
 
 @dataclass
@@ -256,7 +272,17 @@ class QueryExecuted(Event):
     pipeline ("kernel" = fused Pallas kernel, "jnp" = reference path) and
     the ``*_s`` attrs break the wall clock into per-operator phases —
     parse, plan (catalog + routing + scan planning), scan (pooled shard
-    reads), exec (compiled query)."""
+    reads up to the enqueue of the copy to the device), exec (the wait
+    for that copy, the compiled query, the copy back).
+
+    Finer phases, on the profiler's clock as ``repro.query.*`` spans:
+    ``read_s`` (inside scan: shard reads, host filter, concat),
+    ``copy_s`` (from the enqueue of the inputs until they are ready on
+    the device, across the scan/exec boundary), ``device_s`` (the call
+    of the compiled program until its outputs are ready), ``fetch_s``
+    (its outputs back to the host).  ``compiles`` counts the programs
+    the call handed to XLA; ``query_id`` is process-unique and tags the
+    query's ``ScanShardRead`` events."""
 
     kind: ClassVar[str] = "QueryExecuted"
     table: str = ""
@@ -268,6 +294,12 @@ class QueryExecuted(Event):
     plan_s: float = 0.0
     scan_s: float = 0.0
     exec_s: float = 0.0
+    read_s: float = 0.0
+    copy_s: float = 0.0
+    device_s: float = 0.0
+    fetch_s: float = 0.0
+    compiles: int = 0
+    query_id: int = 0
 
 
 # ------------------------------------------------------------ maintenance

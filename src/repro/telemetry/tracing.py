@@ -9,8 +9,9 @@ wall-clock went:
   ``rehydrate`` child span per restored node — a warm run is *all*
   rehydrate spans, which is exactly what the differential cache promised);
 * each stage owns a lane with **queue** (scheduler handoff → driver
-  start), **exec** (scan → execute → write) and **commit** spans; scan
-  shard reads and the stage's logical nodes nest inside exec.  Nodes of
+  start), **exec** (scan → execute → write) and **commit** spans; the
+  stage's phases (read, copy, compile, device, fetch, write), its scan
+  shard reads and its logical nodes nest inside exec.  Nodes of
   a fused stage share the executor window — the platform deliberately
   does not time individual nodes inside one jitted stage function, so
   their spans carry ``fused_with`` instead of fabricated durations;
@@ -48,11 +49,16 @@ from repro.telemetry.events import (
 
 __all__ = ["Span", "RunTrace"]
 
+#: a stage's phases, in the order the stage runs them
+#: (``StageFinished.<phase>_s``)
+STAGE_PHASES = ("read", "copy", "compile", "device", "fetch", "write")
+
 
 @dataclass
 class Span:
     name: str
     #: run | phase | queue | exec | commit | node | scan | rehydrate
+    #: (a stage's read/copy/compile/device/fetch/write are phases too)
     kind: str
     start: float
     end: float
@@ -70,6 +76,40 @@ class Span:
         for c in self.children:
             out.extend(c.walk())
         return out
+
+
+def _stage_phase_spans(f_ev: StageFinished, exec_span: Span) -> List[Span]:
+    """The stage's phases as children of its exec span.
+
+    ``StageFinished`` carries each phase's duration, not its start.  They
+    run in :data:`STAGE_PHASES` order, so read, copy, compile and device
+    are laid from the exec span's start, and write and fetch back from
+    its end: the executor's hand-off falls between device and fetch.
+    Phases an older writer did not record (zero) are left out."""
+    lo, hi = exec_span.start, exec_span.end
+    placed: List[Tuple[str, float, float]] = []
+    cursor = lo
+    for phase in STAGE_PHASES[:4]:
+        dur = getattr(f_ev, f"{phase}_s")
+        placed.append((phase, cursor, min(cursor + dur, hi)))
+        cursor += dur
+    cursor = hi
+    for phase in reversed(STAGE_PHASES[4:]):
+        dur = getattr(f_ev, f"{phase}_s")
+        placed.append((phase, max(cursor - dur, lo), cursor))
+        cursor -= dur
+    return [
+        Span(
+            name=f"{phase} stage {f_ev.stage_id}",
+            kind="phase",
+            start=start,
+            end=max(start, end),
+            lane=exec_span.lane,
+            attrs={"phase": phase, "seconds": getattr(f_ev, f"{phase}_s")},
+        )
+        for phase, start, end in sorted(placed, key=lambda p: p[1])
+        if getattr(f_ev, f"{phase}_s") > 0
+    ]
 
 
 def _union_seconds(intervals: Sequence[Tuple[float, float]]) -> float:
@@ -233,6 +273,10 @@ class RunTrace:
                         "incomplete": f_ev is None,
                     },
                 )
+                if f_ev is not None:
+                    exec_span.children.extend(
+                        _stage_phase_spans(f_ev, exec_span)
+                    )
                 for scan in scans.get(sid, ()):
                     exec_span.children.append(
                         Span(
@@ -367,7 +411,8 @@ class RunTrace:
             show_sched = bool(self.stage_scheduled)
             header = (
                 f"{'stage':>5}  {'queue_ms':>9} {'exec_ms':>9} "
-                f"{'commit_ms':>9}  {'crit':>4}"
+                f"{'commit_ms':>9}  {'crit':>4}  "
+                + " ".join(f"{p + '_ms':>10}" for p in STAGE_PHASES)
             )
             if show_sched:
                 header += f"  {'est_ms':>8} {'src':>7} {'rank':>4} {'adm':>9}"
@@ -378,12 +423,20 @@ class RunTrace:
                 ex = spans.get("exec")
                 co = spans.get("commit")
                 nodes = (q.attrs.get("nodes") if q else None) or []
+                phase_s = {
+                    c.attrs["phase"]: c.attrs["seconds"]
+                    for c in (ex.children if ex else ()) if c.kind == "phase"
+                }
                 row = (
                     f"{sid:>5}  "
                     f"{(q.dur if q else 0) * 1e3:>9.1f} "
                     f"{(ex.dur if ex else 0) * 1e3:>9.1f} "
                     f"{(co.dur if co else 0) * 1e3:>9.1f}  "
-                    f"{'*' if sid in crit else '':>4}"
+                    f"{'*' if sid in crit else '':>4}  "
+                    + " ".join(
+                        f"{phase_s.get(p, 0.0) * 1e3:>10.1f}"
+                        for p in STAGE_PHASES
+                    )
                 )
                 if show_sched:
                     sched = self.stage_scheduled.get(sid)

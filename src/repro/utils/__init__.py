@@ -1,13 +1,10 @@
-"""Shared utilities: hashing, pytree helpers, logging, timing."""
+"""Shared utilities: hashing, pytree helpers, logging."""
 from repro.utils.hashing import stable_hash, content_hash, fingerprint_fn
-from repro.utils.timing import Timer, timed
 from repro.utils.logging import get_logger
 
 __all__ = [
     "stable_hash",
     "content_hash",
     "fingerprint_fn",
-    "Timer",
-    "timed",
     "get_logger",
 ]

@@ -45,11 +45,15 @@ PARALLELISMS = (1, 2, 8)
 #: state at dispatch time (how long the gate held the stage, whether a
 #: compiled executable already existed) — concurrency-dependent by
 #: nature; its cost-model fields (est_cost_s, cp_rank, schedule,
-#: streaming) stay under the invariance contract.
+#: streaming) stay under the invariance contract.  StageFinished's
+#: phases are durations too, and its ``compiles`` depends on which
+#: programs earlier runs in the same process already compiled.
 _TIMING_FIELDS = {
     "ts", "seq", "wall_s", "exec_s", "commit_s", "dur_s",
     "baseline_s", "deadline_s",
     "admission_wait_s", "admission", "warm",
+    "read_s", "copy_s", "compile_s", "device_s", "fetch_s", "write_s",
+    "compiles",
 }
 #: timer-driven events — whether a straggler deadline fires depends on
 #: scheduling noise, so they are excluded from the determinism contract
@@ -240,7 +244,7 @@ def test_trace_spans_nest_and_cover_the_run():
         # queue hands off exactly where exec picks up
         assert q.end == ex.start
         for child in ex.children:
-            assert child.kind in ("scan", "node")
+            assert child.kind in ("phase", "scan", "node")
             assert child.start >= ex.start - eps
             assert child.end <= ex.end + eps
         # every logical node appears inside its stage's exec window
