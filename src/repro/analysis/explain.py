@@ -33,9 +33,11 @@ from repro.analysis.report import Finding, LintReport
 from repro.analysis.types import query_type_findings
 from repro.core.pipeline import Pipeline
 from repro.core.physical import plan_interactive_query
+from repro.engine.exec import group_path
 from repro.engine.expr import Expr
 from repro.engine.query import Query
 from repro.engine.route import (
+    DENSE_MAX_GROUPS,
     RouteDecision,
     RouteError,
     RouteTrace,
@@ -70,6 +72,31 @@ def render_expr(e: Optional[Expr]) -> str:
     return f"{e.op}({', '.join(render_expr(a) for a in e.args)})"
 
 
+def describe_grouping(
+    query: Query, route: Optional[RouteDecision], engine: str
+) -> Optional[str]:
+    """Which group-by runs and over what: the kernel's group axis, the
+    dense path's domain and G, or why the sort path runs.  None for a
+    statement with no aggregation."""
+    path = group_path(query, route)
+    if path == "kernel":
+        return f"kernel, {route.num_groups} group slot(s) from {route.key_offset}"
+    if path == "dense":
+        keys = " x ".join(
+            f"{k} [{off}, {off + size - 1}]"
+            for k, (off, size) in zip(query.group_keys, route.group_domain)
+        )
+        return f"dense, G={route.dense_groups} slot(s): {keys or 'one global group'}"
+    if path == "sort":
+        if engine == "jnp":
+            return "sort (engine='jnp' pins the reference path)"
+        return (
+            "sort (shard statistics do not bound every key within "
+            f"{DENSE_MAX_GROUPS} slots)"
+        )
+    return None
+
+
 def _schema_pairs(schema: Optional[Schema]) -> Optional[Tuple[Tuple[str, str], ...]]:
     if schema is Unknown:
         return None
@@ -91,6 +118,8 @@ class ExplainedQuery:
     #: predicted RouteError message — byte-identical to what the runtime
     #: would raise, positioned fragment and fix hint included
     error: Optional[str] = None
+    #: the group-by that runs and its domain (:func:`describe_grouping`)
+    grouping: Optional[str] = None
     #: filter conjuncts pushed into the FROM table's scan, rendered
     pushdown: Tuple[str, ...] = ()
     #: filter remainder the engine evaluates post-scan, rendered
@@ -123,6 +152,8 @@ class ExplainedQuery:
             lines.append(
                 f"    execute   {self.route.engine_path} — {self.route.reason}"
             )
+            if self.grouping is not None:
+                lines.append(f"    group by  {self.grouping}")
         if self.trace is not None and self.trace.checks:
             lines.append("  route trace:")
             lines.extend(
@@ -147,6 +178,7 @@ class ExplainedQuery:
             "route": self.route.to_json_dict() if self.route else None,
             "trace": self.trace.to_json_dict() if self.trace else None,
             "error": self.error,
+            "grouping": self.grouping,
             "pushdown": list(self.pushdown),
             "residual": self.residual,
             "scans": self.scans,
@@ -220,6 +252,9 @@ def explain_query(
         route=route,
         trace=trace,
         error=error,
+        grouping=(
+            describe_grouping(query, route, engine) if route is not None else None
+        ),
         pushdown=tuple(
             f"{p.column} {p.op} {p.value:g}" for p in pushed
         ),
@@ -245,6 +280,8 @@ class ExplainedNode:
     route: Optional[RouteDecision] = None
     trace: Optional[RouteTrace] = None
     error: Optional[str] = None
+    #: the group-by that runs and its domain (:func:`describe_grouping`)
+    grouping: Optional[str] = None
     output_schema: Optional[Tuple[Tuple[str, str], ...]] = None
 
     def describe(self) -> str:
@@ -256,6 +293,8 @@ class ExplainedNode:
             lines.append(
                 f"  route: {self.route.engine_path} — {self.route.reason}"
             )
+            if self.grouping is not None:
+                lines.append(f"  group by: {self.grouping}")
         if self.trace is not None and self.trace.checks:
             lines.extend(
                 "    " + line
@@ -278,6 +317,7 @@ class ExplainedNode:
             "route": self.route.to_json_dict() if self.route else None,
             "trace": self.trace.to_json_dict() if self.trace else None,
             "error": self.error,
+            "grouping": self.grouping,
             "output_schema": (
                 [list(p) for p in self.output_schema]
                 if self.output_schema is not None
@@ -376,6 +416,10 @@ def explain_pipeline(
                 route=route,
                 trace=trace,
                 error=error,
+                grouping=(
+                    describe_grouping(node.query, route, engine)
+                    if route is not None else None
+                ),
                 output_schema=_schema_pairs(out),
             )
         )

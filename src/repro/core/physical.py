@@ -40,7 +40,12 @@ from repro.engine.columnar import Columnar
 from repro.engine.exec import execute_query
 from repro.engine.expr import Expr
 from repro.engine.query import Query
-from repro.engine.route import RouteDecision, column_stats_for_query, plan_route
+from repro.engine.route import (
+    RouteDecision,
+    column_stats_for_query,
+    plan_route,
+    reassociates,
+)
 from repro.runtime.function import FunctionSpec
 from repro.runtime.resources import CostModel, ResourceRequest
 from repro.table.format import Snapshot
@@ -59,7 +64,9 @@ class PlannerConfig:
     #: the jnp path is provable from shard statistics (engine/route.py),
     #: "kernel" forces it, "jnp" pins the reference path.  NOT part of
     #: node fingerprints — both paths produce identical artifacts, so
-    #: flipping the engine must keep the differential cache warm.
+    #: flipping the engine must keep the differential cache warm.  Only a
+    #: node on the dense group-by that sums is keyed by its group path
+    #: (engine/route.py ``reassociates``).
     sql_engine: str = "auto"
 
 
@@ -383,6 +390,7 @@ def compute_node_fingerprints(
     run_params: Dict[str, Any],
     *,
     edited_node: Optional[str] = None,
+    dense_nodes: frozenset = frozenset(),
 ) -> Dict[str, str]:
     """Per-node transitive identity, independent of fusion grouping.
 
@@ -398,6 +406,12 @@ def compute_node_fingerprints(
     edit; the baseline hashing path is byte-identical when it is unset
     (the payload only gains a key for the salted node).  The lint pass
     uses this to compute cache-invalidation blast radii.
+
+    ``dense_nodes`` names the SQL nodes whose dense group-by may add
+    floats in another order than the reference path
+    (``route.reassociates``); their payload gains the group path, so
+    their entries and the reference path's never stand in for each
+    other.  Every other payload is unchanged.
     """
     fps: Dict[str, str] = {}
     for name in logical.order:
@@ -417,6 +431,8 @@ def compute_node_fingerprints(
         }
         if name == edited_node:
             payload["edited"] = True
+        if name in dense_nodes:
+            payload["group_path"] = "dense"
         fps[name] = stable_hash(payload)
     return fps
 
@@ -566,6 +582,7 @@ def _consult_cache(
     natural: List[List[str]],
     nat_produced_in: Dict[str, int],
     nat_outputs: List[Tuple[str, ...]],
+    dense_nodes: frozenset,
 ) -> Dict[str, NodeCacheEntry]:
     """Which nodes can the cache satisfy?  Node-keyed lookups first; any
     still-unsatisfied natural stage is then matched against legacy
@@ -575,7 +592,8 @@ def _consult_cache(
     cache-unaware grouping of the CURRENT config (computed once by
     ``build_physical_plan``) — old lakes warm up as long as the config
     matches what wrote the legacy entry, and the adopted node entries
-    are config-proof from then on."""
+    are config-proof from then on.  Legacy entries predate the dense
+    group-by, so a stage holding one of ``dense_nodes`` adopts none."""
     satisfied: Dict[str, NodeCacheEntry] = {}
     for name in logical.order:
         node = logical.nodes[name]
@@ -597,7 +615,7 @@ def _consult_cache(
         missing = [
             n for n in (*nat_outputs[sid], *checks) if n not in satisfied
         ]
-        if not missing:
+        if not missing or dense_nodes.intersection(names):
             continue
         legacy = cache.legacy_stage(legacy_fps[sid])
         if legacy is None:
@@ -676,7 +694,28 @@ def build_physical_plan(
     input_ids = input_fingerprints or {
         t: snap.snapshot_id for t, snap in snapshots.items()
     }
-    node_fp = compute_node_fingerprints(logical, input_ids, run_params)
+    # kernel routing per SQL node, decided from shard statistics at plan
+    # time.  Not fingerprinted (the kernel and the reference path produce
+    # identical artifacts, so the cache stays warm across engine flips),
+    # except where the dense group-by may re-associate float sums.
+    routes: Dict[str, RouteDecision] = {}
+    for name in logical.order:
+        node = logical.nodes[name]
+        if node.kind == "sql" and node.query is not None:
+            stats, total_rows = column_stats_for_query(node.query, snapshots)
+            routes[name] = plan_route(
+                node.query,
+                engine=config.sql_engine,
+                stats=stats,
+                total_rows=total_rows,
+            )
+    dense_nodes = frozenset(
+        name for name, route in routes.items()
+        if reassociates(logical.nodes[name].query, route)
+    )
+    node_fp = compute_node_fingerprints(
+        logical, input_ids, run_params, dense_nodes=dense_nodes
+    )
 
     # the natural (cache-unaware) grouping of this config — shared by the
     # legacy-entry match and the materialization-parity restore set below
@@ -691,7 +730,7 @@ def build_physical_plan(
     satisfied = (
         _consult_cache(
             cache, logical, snapshots, run_params, node_fp,
-            nat_stages, nat_produced, nat_outputs_per_stage,
+            nat_stages, nat_produced, nat_outputs_per_stage, dense_nodes,
         )
         if cache is not None
         else {}
@@ -826,21 +865,9 @@ def build_physical_plan(
             and (n in logical.outputs or n in needed_later)
         )
         checks = tuple(n.name for n in nodes if n.is_expectation)
-        # kernel routing per SQL node: decided from shard statistics at
-        # plan time, never fingerprinted (both engines produce identical
-        # artifacts, so the cache stays warm across engine flips)
-        routes: Dict[str, RouteDecision] = {}
-        for node in nodes:
-            if node.kind == "sql" and node.query is not None:
-                stats, total_rows = column_stats_for_query(node.query, snapshots)
-                routes[node.name] = plan_route(
-                    node.query,
-                    engine=config.sql_engine,
-                    stats=stats,
-                    total_rows=total_rows,
-                )
+        stage_routes = {n.name: routes[n.name] for n in nodes if n.name in routes}
         input_order = tuple(sorted(scans)) + internal_inputs
-        fn = _make_stage_fn(nodes, rewrites, input_order, outputs, ctx, routes)
+        fn = _make_stage_fn(nodes, rewrites, input_order, outputs, ctx, stage_routes)
         total_bytes = sum(s.estimated_bytes for s in scans.values())
         # legacy stage fingerprint: parents are topologically earlier
         # stages, so their fingerprints are already in ``transitive``; a
@@ -876,7 +903,7 @@ def build_physical_plan(
                 fingerprint="-".join(logical.nodes[n].fingerprint for n in names),
                 transitive_fingerprint=transitive[sid],
                 parent_stages=tuple(parent_stages),
-                sql_routes=routes,
+                sql_routes=stage_routes,
             )
         )
     executed = {n for names in stage_nodes for n in names}

@@ -254,7 +254,7 @@ class Runner:
             plan_interactive_query,
             resolve_query_snapshots,
         )
-        from repro.engine.exec import compile_query
+        from repro.engine.exec import compile_query, group_path
         from repro.engine.sql import parse_sql
         from repro.table.scan import KERNEL_CHUNK_ROWS
 
@@ -306,7 +306,8 @@ class Runner:
             residual_query = _replace(query, filter_expr=residual)
             joined = {j.table: rels[j.table] for j in query.joins}
             program = compile_query(residual_query, route=route)
-            with phases("device"):
+            grouped_by = group_path(query, route)
+            with phases("device", group_path=grouped_by):
                 out = jax.block_until_ready(
                     program(rels[query.source], joined or None)
                 )
@@ -321,6 +322,7 @@ class Runner:
             shards_read=sum(len(s.shards) for s in scans.values()),
             wall_s=time.perf_counter() - t0,
             engine_path=route.engine_path,
+            group_path=grouped_by,
             parse_s=phases["parse"],
             plan_s=phases["plan"],
             scan_s=scan_s,

@@ -2,9 +2,13 @@
 
 Every operator is shape-stable (masked-row semantics), so a full query —
 and, via core/physical.py, a *chain* of queries plus Python expectations —
-compiles to a single XLA program.  Group-by uses a sort + segment-scatter
-formulation (radix-style grouping adapted to TPU-friendly dense ops: sort,
-cumsum, scatter-add are all well-supported lax primitives).  Joins compile
+compiles to a single XLA program.  The reference group-by uses a sort +
+segment-scatter formulation (radix-style grouping adapted to TPU-friendly
+dense ops: sort, cumsum, scatter-add are all well-supported lax
+primitives).  When the route carries a group domain (shard statistics
+bound every key, engine/route.py), the group-by instead reduces over a
+static slot axis with masked reductions: no sort, no gather, no scatter,
+and an output of one row per slot.  Joins compile
 to a shape-stable first-match gather: the right side is sorted once
 (valid rows first), probe keys binary-search into it, and misses either
 invalidate the row (inner) or zero-fill the gathered columns (left) — no
@@ -30,6 +34,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.engine.columnar import Columnar
 from repro.engine.query import Agg, Query
@@ -332,32 +337,97 @@ def _kernel_filter_agg(rel: Columnar, query: Query, route: RouteDecision) -> Col
         )
         sums_by_col[cname] = sums_f
 
-    counts_i = counts_f.astype(jnp.int32)
-    present = counts_i > 0
-    # jnp layout: present groups first, ascending key (slot index ==
-    # key - offset, so ascending slot == ascending key)
-    order = jnp.argsort((~present).astype(jnp.int32), stable=True)
-    present_s = present[order]
-    keys_out = (jnp.arange(G, dtype=jnp.int32) + route.key_offset)[order]
+    counts = counts_f.astype(jnp.int32)
+    # slot index == key - offset, so ascending slot == ascending key
     out_cols: Dict[str, jax.Array] = {
-        out_key: jnp.where(
-            present_s, keys_out.astype(key_col.dtype), jnp.zeros((), key_col.dtype)
+        out_key: (jnp.arange(G, dtype=jnp.int32) + route.key_offset).astype(
+            key_col.dtype
         )
     }
-    counts_s = jnp.where(present_s, counts_i[order], 0)
     for agg in query.aggregates:
         if agg.fn == "count":
-            out_cols[agg.name] = counts_s
+            out_cols[agg.name] = counts
             continue
-        sums_s = jnp.where(present_s, sums_by_col[agg.expr.args[0]][order], 0.0)
+        sums = sums_by_col[agg.expr.args[0]]
         if agg.fn == "sum":
             vdtype = rel.column(agg.expr.args[0]).dtype
-            out_cols[agg.name] = sums_s.astype(
+            out_cols[agg.name] = sums.astype(
                 vdtype if vdtype.kind == "f" else jnp.int32
             )
         else:  # mean
-            out_cols[agg.name] = sums_s / jnp.maximum(counts_s, 1).astype(jnp.float32)
-    return Columnar(out_cols, present_s)
+            out_cols[agg.name] = sums / jnp.maximum(counts, 1).astype(jnp.float32)
+    return _present_first(counts > 0, out_cols)
+
+
+def _present_first(present: jax.Array, slot_cols: Dict[str, jax.Array]) -> Columnar:
+    """Slot-indexed group outputs in apply_groupby's layout: present
+    groups first, in ascending slot order, absent slots zeroed — so the
+    compacted result equals the sort path's."""
+    order = jnp.argsort((~present).astype(jnp.int32), stable=True)
+    present_s = present[order]
+    out = {
+        name: jnp.where(present_s, c[order], jnp.zeros((), c.dtype))
+        for name, c in slot_cols.items()
+    }
+    return Columnar(out, present_s)
+
+
+# ---------------------------------------------------------- dense group-by
+def _dense_groupby(rel: Columnar, query: Query, route: RouteDecision) -> Columnar:
+    """Group over the static slot axis of ``route.group_domain`` (one
+    ``(offset, size)`` per key, ``route.dense_groups`` slots) with masked
+    reductions.
+
+    A row's slot is ``sum((key_i - offset_i) * stride_i)``, the first key
+    most significant, so ascending slot is ascending lexicographic key
+    order.  Each aggregate is one reduction over a ``[G, N]`` compare and
+    select that XLA fuses into the reduce: nothing of that shape is
+    written out, and nothing is sorted, gathered or scattered.  Output
+    capacity is G, in the sort path's layout (:func:`_present_first`);
+    float sums differ from it only in the order of addition.
+    """
+    domain, G = route.group_domain, route.dense_groups
+    sizes = [size for _, size in domain]
+    slot = jnp.zeros((rel.capacity,), jnp.int32)
+    live = rel.valid
+    for k, (offset, size) in zip(query.group_keys, domain):
+        kcol = rel.column(k)
+        if kcol.dtype.kind not in ("i", "u", "b"):
+            raise TypeError(f"group key {k!r} must be integer/bool, got {kcol.dtype}")
+        digit = kcol.astype(jnp.int32) - offset
+        # keys outside the statistics' bounds belong to no slot
+        live = live & (digit >= 0) & (digit < size)
+        slot = slot * size + digit
+    hit = jnp.where(live, slot, -1)[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
+
+    counts = jnp.sum(hit, axis=1, dtype=jnp.int32)
+    # each key's value per slot: the slot's digit in the mixed radix
+    digits = np.unravel_index(np.arange(G), sizes) if sizes else ()
+    out_cols: Dict[str, jax.Array] = {
+        k: jnp.asarray(d + offset, jnp.int32).astype(rel.column(k).dtype)
+        for k, d, (offset, _) in zip(query.group_keys, digits, domain)
+    }
+    for agg in query.aggregates:
+        out_cols[agg.name] = _dense_agg(rel, agg, hit, counts)
+    return _present_first(counts > 0, out_cols)
+
+
+def _dense_agg(rel: Columnar, agg: Agg, hit: jax.Array, counts: jax.Array) -> jax.Array:
+    """One aggregate per slot; dtypes and fills as :func:`_apply_one_agg`."""
+    if agg.fn == "count":
+        return counts
+    vals = agg.expr.evaluate(rel.columns)[None, :]
+    if agg.fn in ("sum", "mean"):
+        acc_dtype = vals.dtype if vals.dtype.kind == "f" else jnp.int32
+        total = jnp.sum(jnp.where(hit, vals.astype(acc_dtype), 0), axis=1)
+        if agg.fn == "sum":
+            return total
+        return total.astype(jnp.float32) / jnp.maximum(counts, 1).astype(jnp.float32)
+    if agg.fn == "min":
+        return jnp.min(jnp.where(hit, vals, _extreme(vals.dtype, +1)), axis=1)
+    if agg.fn == "max":
+        return jnp.max(jnp.where(hit, vals, _extreme(vals.dtype, -1)), axis=1)
+    raise ValueError(f"unsupported aggregate {agg.fn!r}")
 
 
 def apply_sort(rel: Columnar, query: Query) -> Columnar:
@@ -402,8 +472,9 @@ def execute_query(
 
     ``joined`` maps each JOIN table name to its relation; ``route`` is an
     optional engine/route.py decision — ``"kernel"`` sends the
-    filter+group+agg pipeline through the fused Pallas kernel, anything
-    else (including no route at all) runs the reference jnp operators.
+    filter+group+agg pipeline through the fused Pallas kernel, a jnp
+    route with a ``group_domain`` groups without a sort, anything else
+    (including no route at all) runs the reference jnp operators.
     """
     rel, display = _combined_relation(query, rel, joined)
     if route is not None and route.engine_path == "kernel" and query.is_aggregation:
@@ -414,7 +485,10 @@ def execute_query(
         rel = apply_filter(rel, query)
         if query.is_aggregation:
             grel, gquery = _normalize_group_keys(rel, query)
-            rel = apply_groupby(grel, gquery, capacity=group_capacity)
+            if route is not None and route.group_domain is not None:
+                rel = _dense_groupby(grel, gquery, route)
+            else:
+                rel = apply_groupby(grel, gquery, capacity=group_capacity)
             if query.projections:
                 rel = apply_projection(rel, query)
         else:
@@ -425,6 +499,19 @@ def execute_query(
     rel = apply_sort(rel, query)
     rel = apply_limit(rel, query)
     return rel
+
+
+def group_path(query: Query, route: Optional[RouteDecision]) -> str:
+    """Which group-by :func:`execute_query` runs for ``query`` under
+    ``route``: ``"kernel"``, ``"dense"``, ``"sort"``, or ``""`` for a
+    statement with no aggregation."""
+    if not query.is_aggregation:
+        return ""
+    if route is not None and route.engine_path == "kernel":
+        return "kernel"
+    if route is not None and route.group_domain is not None:
+        return "dense"
+    return "sort"
 
 
 def program_name(query: Query) -> str:

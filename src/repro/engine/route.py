@@ -1,10 +1,13 @@
 """Kernel-routing eligibility: which engine executes a query's hot path.
 
 The planner calls :func:`plan_route` per SQL node and stamps the
-resulting :class:`RouteDecision` onto the compiled stage.  The decision
-is **not** part of node fingerprints — both engines produce byte-identical
-artifacts (that is what the eligibility guards prove), so the cache must
-stay warm regardless of which path ran.
+resulting :class:`RouteDecision` onto the compiled stage.  The kernel
+route is **not** part of node fingerprints — the kernel and the jnp
+reference produce byte-identical artifacts (that is what the eligibility
+guards prove), so the cache must stay warm regardless of which ran.  The
+dense group-by below is the one exception: it adds SUM/AVG arguments in
+another order than the reference, so a node for which
+:func:`reassociates` holds names its group path in its fingerprint.
 
 Routing rules (``engine="auto"``):
 
@@ -22,6 +25,14 @@ Routing rules (``engine="auto"``):
   columns always take the jnp path under ``auto``: float addition is
   non-associative and the two paths order it differently.
 
+An aggregation the kernel does not take (it bails at R202-R208) still
+gets a *group domain* when shard statistics bound every group key:
+one ``(offset, size)`` per key, with the product of sizes at most
+``DENSE_MAX_GROUPS``.  The executor then groups over that static slot
+axis with masked reductions instead of sorting (engine/exec.py).
+Integer, key, COUNT and MIN/MAX outputs equal the sort path's byte for
+byte; float sums and means differ from ``engine="jnp"`` in the low bits.
+
 ``engine="kernel"`` forces the kernel for structurally-eligible queries
 (skipping the exactness guards — float results may then differ in the
 last ulp) and raises when the query shape or missing key statistics make
@@ -37,6 +48,7 @@ of the evidence trail.
 """
 from __future__ import annotations
 
+import math
 from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -56,6 +68,13 @@ EXACT_BOUND = 2 ** 24
 
 #: default cap on the kernel's dense group axis (one-hot VMEM bound)
 DEFAULT_MAX_GROUPS = 1024
+
+#: largest group domain (product of the keys' value ranges) the jnp path
+#: groups over a static slot axis instead of sorting; chosen from a sweep
+#: on a TPU v5e (PERF.md, section 6)
+DENSE_MAX_GROUPS = 16384
+
+_INT32 = (-(2 ** 31), 2 ** 31 - 1)
 
 _PRED_TO_KERNEL_OP = {"<": "lt", "<=": "le", ">": "gt", ">=": "ge", "==": "eq", "!=": "ne"}
 
@@ -226,7 +245,11 @@ class RouteDecision:
     Query itself.  ``num_groups``/``key_offset`` size the kernel's dense
     group axis (slot = key - offset); ``native_filter`` means the WHERE
     clause is a single ``col <cmp> literal`` the kernel evaluates
-    in-register instead of taking a precomputed mask.  ``trace`` carries
+    in-register instead of taking a precomputed mask.  ``group_domain``
+    is one ``(offset, size)`` per group key, in key order (``()`` for a
+    global aggregation), on a jnp decision whose keys the statistics
+    bound: the executor groups over that static slot axis without a
+    sort.  None keeps the sort-based reference path.  ``trace`` carries
     the evidence (every check evaluated) but is excluded from
     equality/hash — routing identity is the semantic fields only."""
 
@@ -235,7 +258,15 @@ class RouteDecision:
     num_groups: int = 0
     key_offset: int = 0
     native_filter: bool = False
+    group_domain: Optional[Tuple[Tuple[int, int], ...]] = None
     trace: Optional[RouteTrace] = field(default=None, compare=False, repr=False)
+
+    @property
+    def dense_groups(self) -> Optional[int]:
+        """Slots of the group domain (G), or None without one."""
+        if self.group_domain is None:
+            return None
+        return math.prod(size for _, size in self.group_domain)
 
     def to_json_dict(self) -> Dict[str, Any]:
         return {
@@ -244,12 +275,67 @@ class RouteDecision:
             "num_groups": self.num_groups,
             "key_offset": self.key_offset,
             "native_filter": self.native_filter,
+            "group_domain": (
+                [list(d) for d in self.group_domain]
+                if self.group_domain is not None else None
+            ),
             "trace": self.trace.to_json_dict() if self.trace else None,
         }
 
 
-def _jnp(reason: str, trace: Optional[RouteTrace] = None) -> RouteDecision:
-    return RouteDecision("jnp", reason, trace=trace)
+def _jnp(
+    reason: str,
+    trace: Optional[RouteTrace] = None,
+    group_domain: Optional[Tuple[Tuple[int, int], ...]] = None,
+) -> RouteDecision:
+    return RouteDecision("jnp", reason, group_domain=group_domain, trace=trace)
+
+
+def reassociates(query: Query, route: RouteDecision) -> bool:
+    """Whether ``route`` may give ``query`` other bytes than the
+    ``engine="jnp"`` reference: a dense group-by that sums or averages
+    adds in another order, which moves the low bits of float sums.
+    Integer sums are exact either way, but dtypes are not known here, so
+    they count too."""
+    return route.group_domain is not None and any(
+        agg.fn in ("sum", "mean") for agg in query.aggregates
+    )
+
+
+def _widen_for_left_join(
+    query: Query, key: str, kmin: int, kmax: int
+) -> Tuple[int, int]:
+    """A left join zero-fills unmatched right-side rows, so a group key
+    that may come from a left-joined table must admit the value 0 (an
+    unqualified key's owner is unknown here — extend conservatively)."""
+    left_quals = {j.qualifier for j in query.joins if j.how == "left"}
+    if left_quals:
+        owner = key.split(".")[0] if "." in key else None
+        if owner is None or owner in left_quals:
+            return min(kmin, 0), max(kmax, 0)
+    return kmin, kmax
+
+
+def _group_domain(
+    query: Query, stats: Dict[str, Tuple[int, int]]
+) -> Optional[Tuple[Tuple[int, int], ...]]:
+    """``(offset, size)`` per group key when integer statistics bound
+    every key (left-join widening applied, int32 range) and the domain
+    has at most ``DENSE_MAX_GROUPS`` slots; ``()`` for a global
+    aggregation; else None."""
+    domain = []
+    slots = 1
+    for key in query.group_keys:
+        if key not in stats:
+            return None
+        kmin, kmax = _widen_for_left_join(query, key, *stats[key])
+        if kmin < _INT32[0] or kmax > _INT32[1]:
+            return None
+        slots *= kmax - kmin + 1
+        if slots > DENSE_MAX_GROUPS:
+            return None
+        domain.append((kmin, kmax - kmin + 1))
+    return tuple(domain)
 
 
 def native_filter_of(expr: Optional[Expr]) -> Optional[Tuple[str, str, float]]:
@@ -351,7 +437,8 @@ def plan_route(
                 hint=last.hint,
                 trace=trace,
             )
-        return _jnp(reason, trace)
+        domain = _group_domain(query, stats) if query.is_aggregation else None
+        return _jnp(reason, trace, domain)
 
     # ---------------------------------------------------------- structure
     if not record(
@@ -401,17 +488,8 @@ def plan_route(
         token=key,
     ):
         return bail(f"no integer statistics for group key {key!r}")
-    kmin, kmax = stats[key]
-    # a left join zero-fills unmatched right-side rows, so a group key
-    # that may come from a left-joined table must admit slot value 0
-    # (an unqualified key's owner is unknown here — extend conservatively)
-    widened = False
-    left_quals = {j.qualifier for j in query.joins if j.how == "left"}
-    if left_quals:
-        owner = key.split(".")[0] if "." in key else None
-        if owner is None or owner in left_quals:
-            widened = (kmin, kmax) != (min(kmin, 0), max(kmax, 0))
-            kmin, kmax = min(kmin, 0), max(kmax, 0)
+    kmin, kmax = _widen_for_left_join(query, key, *stats[key])
+    widened = (kmin, kmax) != stats[key]
     num_groups = kmax - kmin + 1
     if not record(
         "R206", num_groups <= max_groups,

@@ -44,7 +44,11 @@ when the decision is *provably byte-identical* to the jnp reference:
   evaluated by the jnp expression tree and fed to the kernel as a mask.
 
 ``engine="kernel"`` forces the route (structural impossibility raises
-``RouteError``); ``engine="jnp"`` pins the reference path.  Routing is
-never part of node fingerprints — both engines produce byte-identical
-artifacts, so cache entries stay warm across engine switches.
+``RouteError``); ``engine="jnp"`` pins the reference path.  The kernel
+route is never part of node fingerprints — both engines produce
+byte-identical artifacts, so cache entries stay warm across engine
+switches.  A jnp-routed aggregation whose keys the statistics bound runs
+the dense group-by instead, whose float sums differ from the reference
+in the low bits; a node that takes it and sums names its group path in
+its fingerprint (``engine/route.py``, ``reassociates``).
 """

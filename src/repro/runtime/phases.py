@@ -61,10 +61,11 @@ class Phases:
         self._compiles_at_start = compiles()
 
     @contextlib.contextmanager
-    def __call__(self, phase: str) -> Iterator[None]:
+    def __call__(self, phase: str, **args: Any) -> Iterator[None]:
+        """Time ``phase``; ``args`` ride on its span as trace metadata."""
         t0 = time.perf_counter()
         try:
-            with jax.profiler.TraceAnnotation(f"{self.prefix}.{phase}"):
+            with jax.profiler.TraceAnnotation(f"{self.prefix}.{phase}", **args):
                 yield
         finally:
             self.seconds[phase] = (

@@ -269,7 +269,9 @@ class QueryExecuted(Event):
     """One interactive query completed (point-wise path, paper 4.6).
 
     ``engine_path`` records which engine ran the filter+group+agg
-    pipeline ("kernel" = fused Pallas kernel, "jnp" = reference path) and
+    pipeline ("kernel" = fused Pallas kernel, "jnp" = reference path),
+    ``group_path`` which group-by ran ("kernel", "dense" over a static
+    slot axis, "sort", or "" for a statement with no aggregation), and
     the ``*_s`` attrs break the wall clock into per-operator phases —
     parse, plan (catalog + routing + scan planning), scan (pooled shard
     reads up to the enqueue of the copy to the device), exec (the wait
@@ -290,6 +292,7 @@ class QueryExecuted(Event):
     shards_read: int = 0
     wall_s: float = 0.0
     engine_path: str = "jnp"
+    group_path: str = ""
     parse_s: float = 0.0
     plan_s: float = 0.0
     scan_s: float = 0.0

@@ -10,8 +10,9 @@ Four contracts under test:
 * the kernel route is byte-identical to the jnp reference wherever
   ``engine="auto"`` takes it (and the router refuses everything it
   cannot prove exact), across dtypes, group cardinalities, empty-after-
-  filter, and parallelism levels — engine choice never touches
-  artifacts or fingerprints;
+  filter, and parallelism levels — the kernel route never touches
+  artifacts or fingerprints, and a node whose dense group-by sums floats
+  is keyed by its group path;
 * ``client.query`` resolves every table name against the catalog with
   zero registration, scans through the pooled chunked feed, and reports
   its engine path + phase breakdown on ``QueryExecuted``.
@@ -385,7 +386,15 @@ def test_client_select_star_over_join(lake):
 
 
 # ---------------------------- pipeline parity: parallelism x engine
-def _run_join_pipeline(parallelism, sql_engine, rng):
+def _float_trips(rng, n=N_TRIPS):
+    """Trips whose fares have fractions, so their f32 sums depend on the
+    order of addition."""
+    trips = _trips(rng, n)
+    trips["fare"] = (rng.random(n) * 50 + 1).astype(np.float32)
+    return trips
+
+
+def _run_join_pipeline(parallelism, sql_engine, rng, trips=_trips):
     p = Pipeline("sql_v2_parity")
     p.sql("by_borough", JOIN_SQL, materialize=True)
     with Client.ephemeral(
@@ -394,7 +403,7 @@ def _run_join_pipeline(parallelism, sql_engine, rng):
             max_workers=8, max_concurrent_stages=parallelism
         ),
     ) as client:
-        client.write_table("trips", _trips(rng))
+        client.write_table("trips", trips(rng))
         client.write_table("zones", _zones())
         handle = client.run(
             p,
@@ -420,6 +429,26 @@ def test_pipeline_parity_parallelism_x_engine(rng):
                 )
 
 
+def test_pipeline_parity_float_sums_by_engine():
+    """Float SUMs: the kernel (forced), the dense group-by (auto) and the
+    sort reference (jnp) agree to f32 rounding; each engine writes the
+    same artifacts at every parallelism; keys and counts are exact."""
+    runs = {
+        (parallelism, engine): _run_join_pipeline(
+            parallelism, engine, np.random.default_rng(5), _float_trips
+        )
+        for parallelism in (1, 8)
+        for engine in ("auto", "kernel", "jnp")
+    }
+    base_out = runs[(1, "jnp")][1]
+    for (parallelism, engine), (art, out) in runs.items():
+        assert art == runs[(1, engine)][0], (parallelism, engine)
+        for k in ("borough", "count"):
+            np.testing.assert_array_equal(out[k], base_out[k])
+        assert out["total"].dtype == np.float32
+        np.testing.assert_allclose(out["total"], base_out["total"], rtol=1e-5)
+
+
 def test_engine_switch_keeps_cache_warm(rng):
     """Routing is not fingerprinted: a warm cache built under one engine
     must fully satisfy a re-run under the other."""
@@ -437,6 +466,30 @@ def test_engine_switch_keeps_cache_warm(rng):
         ).raise_for_state()
         assert warm.stats["cache"]["nodes_executed"] == 0
         assert warm.stats["cache"]["hits"] >= 1
+
+
+def test_engine_switch_float_sums_follow_group_path(rng):
+    """A node whose dense group-by sums floats (auto) is keyed by its
+    group path: the sort reference's entry (jnp) is never served for it,
+    nor the other way round, and each stays warm for its own path.  The
+    kernel and the reference path still share one entry."""
+    p = Pipeline("sql_v2_cache_float")
+    p.sql("by_borough", JOIN_SQL, materialize=True)
+    with Client.ephemeral(shard_rows=512) as client:
+        client.write_table("trips", _float_trips(rng))
+        client.write_table("zones", _zones())
+
+        def executed(engine):
+            handle = client.run(
+                p, planner_config=PlannerConfig(sql_engine=engine)
+            ).raise_for_state()
+            return handle.stats["cache"]["nodes_executed"]
+
+        assert executed("auto") >= 1
+        assert executed("jnp") >= 1
+        assert executed("auto") == 0
+        assert executed("jnp") == 0
+        assert executed("kernel") == 0
 
 
 def test_single_table_fingerprints_unchanged():
