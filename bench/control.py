@@ -24,6 +24,7 @@ from typing import Dict
 sys.path[:0] = [str(Path(__file__).resolve().parent)]
 
 import harness  # noqa: E402
+import reference  # noqa: E402
 
 
 def control_numbers(cell: harness.Cell, seed: int) -> Dict[str, float]:
@@ -34,17 +35,21 @@ def control_numbers(cell: harness.Cell, seed: int) -> Dict[str, float]:
         traffic = harness.Traffic(mix, seed)
         keys = traffic.every()
         got = harness.query_answers(traffic, keys, tables, precision="bf16")
-        requests = [harness.Request(r, p, traffic.op(r), 0.0, 0.0, got[(r, p)]) for r, p in keys]
+        requests = [harness.Request(r, p, traffic.op(r), 0.0, 0.0,
+                                    reference.head(got[(r, p)], traffic.statement(r)))
+                    for r, p in keys]
         want = harness.query_answers(traffic, keys, tables)
         return harness.judge_queries(mix, traffic, requests, 0, want)[0]
     spec = mix["pipeline"]
-    env, verdicts = harness.pipeline_reference(spec, tables)
+    answers, verdicts = harness.pipeline_reference(spec, tables)
     low, low_verdicts = harness.pipeline_reference(spec, tables, precision="bf16")
     handle = SimpleNamespace(state=SimpleNamespace(name="SUCCESS"),
                              checks=low_verdicts, merged_commit="control")
-    read_back = {name: low[name] for name in spec["read_back"]}
+    nodes = {n["name"]: n for n in spec["nodes"]}
+    read_back = {name: reference.head(low[name], nodes[name]["statement"])
+                 for name in spec["read_back"]}
     return harness.judge_runs(spec, [harness.Request(0, None, "run", 0.0, 0.0, handle)],
-                              read_back, ["control"], env, verdicts)[0]
+                              read_back, ["control"], answers, verdicts)[0]
 
 
 def main() -> int:

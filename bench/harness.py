@@ -6,15 +6,18 @@ harness finds the rest by those names:
 * ``configs/<config>.json`` holds the configuration's sizes, and
   ``configs/<config>.py`` beside it generates its tables from the seed;
 * ``mixes/<traffic>.json`` holds the mix: its statements (SQL for the
-  program, a structured form for the reference), their weights and
-  parameters, the number of clients, and the limits of the comparison;
+  program, a structured form for the reference, which may join tables
+  and cut at a LIMIT: ``reference.py`` gives the form), their weights
+  and parameters, the number of clients, and the limits of the
+  comparison;
 * ``metrics/<metric>.py`` reads one metric from what a run measured.
 
 A run builds a lake in a temporary directory, writes the tables, warms
 every statement of the mix once, then measures for ``seconds``.  A
 query mix offers ``Client.query`` requests in an open loop at the mix's
-fixed rate, served by its workers; a pipeline mix has one client run
-``Client.run`` back to back.  Once the window has closed and every
+fixed rate, served by its workers, threads that set-up starts and that
+each run every request once before the window opens; a pipeline mix
+has one client run ``Client.run`` back to back.  Once the window has closed and every
 request has returned, the answers are compared with the numpy reference
 (``reference.py``).
 """
@@ -347,36 +350,69 @@ def annotate(traced: bool, name: str):
     return jax.profiler.TraceAnnotation(devicetrace.ANNOTATION_PREFIX + name)
 
 
-def _query_window(client, traffic: Traffic, workers: int, rate: float,
-                  seconds: float, traced: bool,
-                  ) -> Tuple[float, float, List[Request], int, float]:
-    """Open loop: request ``k`` is due ``k / rate`` seconds after the open,
-    for ``seconds``, whatever the system does; ``workers`` threads serve
-    the requests in the order they fall due.  A request's latency runs
-    from when it was due to its return, so a wait for a free worker
-    counts.  Returns the window's open, the time the last request
-    returned (or the close), the requests, how many never returned
-    within ``DRAIN_S`` of the close, and how late the sender ran at most."""
-    plan = traffic.plan(max(1, round(rate * seconds)))
-    inbox: "queue.Queue[Optional[Tuple[int, Optional[int], float, float]]]" = queue.Queue()
-    requests: List[Request] = []
+class Workers:
+    """The threads that serve a query mix's windows, started in set-up.
 
-    def serve() -> None:
-        while (item := inbox.get()) is not None:
-            r, p, due, sent = item
+    Each thread runs every request of the mix once (its first parameters)
+    before the first window opens: a thread's first query is slower than
+    its later ones (TPC-H Q1 on a TPU v5e host: 0.56-0.60 s against
+    0.41-0.46 s), and without this the window's first request on each
+    thread would pay for it."""
+
+    def __init__(self, client, traffic: Traffic, n: int, traced: bool):
+        self.client, self.traffic, self.traced = client, traffic, traced
+        self.inbox: "queue.Queue[Optional[tuple]]" = queue.Queue()
+        self.errors: List[BaseException] = []
+        ready = threading.Barrier(n + 1)
+        self.threads = [threading.Thread(target=self._serve, args=(ready,), daemon=True,
+                                         name=f"bench-worker-{w}") for w in range(n)]
+        for t in self.threads:
+            t.start()
+        ready.wait()
+        if self.errors:
+            self.close()
+            raise self.errors[0]
+
+    def _serve(self, ready: threading.Barrier) -> None:
+        traffic = self.traffic
+        try:
+            for r, req in enumerate(traffic.requests):
+                self.client.query(traffic.sql(r, 0 if "params" in req else None))
+        except Exception as e:
+            self.errors.append(e)
+        finally:
+            ready.wait()
+        while (item := self.inbox.get()) is not None:
+            r, p, due, sent, out, done = item
             called = time.perf_counter()
             try:
-                with annotate(traced, traffic.name(r)):
-                    answer = client.query(traffic.sql(r, p))
+                with annotate(self.traced, traffic.name(r)):
+                    answer = self.client.query(traffic.sql(r, p))
             except Exception as e:  # an answer that never came is judged
                 answer = e
-            requests.append(Request(r, p, traffic.op(r), due, time.perf_counter(), answer,
-                                    called, sent=sent))
+            out.append(Request(r, p, traffic.op(r), due, time.perf_counter(), answer,
+                               called, sent=sent))
+            done.release()
 
-    threads = [threading.Thread(target=serve, daemon=True, name=f"bench-worker-{w}")
-               for w in range(workers)]
-    for t in threads:
-        t.start()
+    def close(self) -> None:
+        for _ in self.threads:
+            self.inbox.put(None)
+        for t in self.threads:
+            t.join(timeout=DRAIN_S)
+
+
+def _query_window(workers: Workers, rate: float, seconds: float,
+                  ) -> Tuple[float, float, List[Request], int, float]:
+    """Open loop: request ``k`` is due ``k / rate`` seconds after the open,
+    for ``seconds``, whatever the system does; the ``workers`` serve the
+    requests in the order they fall due.  A request's latency runs from
+    when it was due to its return, so a wait for a free worker counts.
+    Returns the window's open, the time the last request returned (or
+    the close), the requests, how many never returned within ``DRAIN_S``
+    of the close, and how late the sender ran at most."""
+    plan = workers.traffic.plan(max(1, round(rate * seconds)))
+    out: List[Request] = []
+    done = threading.Semaphore(0)
     t_open = time.perf_counter()
     late = 0.0
     for k, (r, p) in enumerate(plan):
@@ -385,12 +421,12 @@ def _query_window(client, traffic: Traffic, workers: int, rate: float,
             time.sleep(wait)
         sent = time.perf_counter()
         late = max(late, sent - due)
-        inbox.put((r, p, due, sent))
-    for _ in threads:
-        inbox.put(None)
+        workers.inbox.put((r, p, due, sent, out, done))
     deadline = t_open + seconds
-    for t in threads:
-        t.join(timeout=max(0.0, deadline + DRAIN_S - time.perf_counter()))
+    for _ in plan:
+        if not done.acquire(timeout=max(0.0, deadline + DRAIN_S - time.perf_counter())):
+            break
+    requests = list(out)
     hung = len(plan) - len(requests)
     close = max([r.end for r in requests], default=deadline)
     return t_open, max(close, deadline), requests, hung, late
@@ -415,7 +451,8 @@ def _run_window(client, pipeline, spec: Mapping[str, Any], seconds: float,
 
 # -------------------------------------------------------------- judgement
 def query_answers(traffic: Traffic, keys, tables, precision: str = "exact"):
-    """The reference's answer to each ``(request, param)``."""
+    """The reference's whole ordered answer to each ``(request, param)``,
+    before its LIMIT (``reference.head`` cuts it)."""
     return {
         (r, p): reference.run_statement(traffic.statement(r), tables,
                                         traffic.params(r, p), precision)
@@ -433,11 +470,12 @@ def judge_queries(mix, traffic: Traffic, requests: List[Request], hung: int,
     limits = mix["limits"]
     wrong, rel, failed = 0, 0.0, hung
     for req in requests:
-        ref = want[(req.request, req.param)]
+        ref, stmt = want[(req.request, req.param)], traffic.statement(req.request)
         if isinstance(req.answer, BaseException):
-            wrong, failed = wrong + max(len(next(iter(ref.values()), ())), 1), failed + 1
+            wrong += max(reference.kept_rows(stmt, reference.rows(ref)), 1)
+            failed += 1
             continue
-        w, r = reference.compare(req.answer, ref, traffic.statement(req.request))
+        w, r = reference.compare(req.answer, ref, stmt, limits.get("float_rel_err", 0.0))
         wrong += w
         failed += bool(w) or r > limits.get("float_rel_err", float("inf"))
         rel = max(rel, r)
@@ -448,27 +486,30 @@ def judge_queries(mix, traffic: Traffic, requests: List[Request], hung: int,
 
 
 def pipeline_reference(spec, tables, precision: str = "exact"):
-    """The reference's tables for each SQL node, and each expectation's
-    verdict."""
+    """The reference's whole ordered answer for each SQL node, and each
+    expectation's verdict.  A node reads its parents' answers as SQL
+    gives them, cut at their LIMIT."""
     env = dict(tables)
-    verdicts = {}
+    answers, verdicts = {}, {}
     for node in spec["nodes"]:
         if node["kind"] == "sql":
-            env[node["name"]] = reference.run_statement(
+            answers[node["name"]] = reference.run_statement(
                 node["statement"], env, precision=precision)
+            env[node["name"]] = reference.head(answers[node["name"]], node["statement"])
         else:
             data = env[node["input"]][node["column"]]
             if precision == "bf16":
                 data = data.astype(np.float32).astype(reference.BF16)
             verdicts[node["name"]] = reference.run_expectation(
                 node, {node["input"]: {node["column"]: data}})
-    return env, verdicts
+    return answers, verdicts
 
 
 def judge_runs(spec, requests: List[Request], read_back, head_log: List[str],
-               env, verdicts) -> Tuple[Dict[str, float], int]:
+               answers, verdicts) -> Tuple[Dict[str, float], int]:
     """Each run's state and expectation verdicts, every run's merge into
-    the branch, and the artifacts read back from the branch's head."""
+    the branch, and the artifacts read back from the branch's head,
+    against the reference's ``answers`` (``pipeline_reference``)."""
     failed = 0
     merged = []
     for req in requests:
@@ -486,11 +527,11 @@ def judge_runs(spec, requests: List[Request], read_back, head_log: List[str],
     wrong = 0
     nodes = {n["name"]: n for n in spec["nodes"]}
     for name in spec["read_back"]:
-        got = read_back.get(name)
+        got, stmt = read_back.get(name), nodes[name]["statement"]
         if got is None:
-            wrong += max(len(next(iter(env[name].values()))), 1)
+            wrong += max(reference.kept_rows(stmt, reference.rows(answers[name])), 1)
             continue
-        wrong += reference.compare(got, env[name], nodes[name]["statement"])[0]
+        wrong += reference.compare(got, answers[name], stmt)[0]
     return {"wrong_cells": float(wrong), "wrong_runs": float(failed + unmerged)}, \
         failed
 
@@ -556,8 +597,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     compiles = CompileLog()
     with compiles.listening(), tempfile.TemporaryDirectory(prefix="bench_") as tmp:
         client = repro.Client(Path(tmp) / "lake")
+        workers = None
         try:
             traffic, pipeline = prepare(cell, tables, seed, client, started, log)
+            if kind == "query":
+                workers = Workers(client, traffic, int(mix["workers"]), traced)
             log(f"warmed ({time.perf_counter() - started:.3f} s, {len(compiles.compiles)} "
                 f"programs handed to XLA, {len(compiles.misses)} persistent-cache misses)")
 
@@ -571,8 +615,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
                     annotate(traced, "window"):
                 if kind == "query":
                     t_open, t_close, requests, hung, late = _query_window(
-                        client, traffic, int(mix["workers"]), float(mix["rate_per_s"]),
-                        seconds, traced)
+                        workers, float(mix["rate_per_s"]), seconds)
                 else:
                     t_open, t_close, requests = _run_window(
                         client, pipeline, mix["pipeline"], seconds, traced)
@@ -597,6 +640,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
                 head_log = [c.commit_id for c in
                             client.log(spec["branch"], limit=len(requests) + 16)]
         finally:
+            if workers is not None:
+                workers.close()
             client.close()
 
     log(f"sender ran late by at most {1e3 * late:.3f} ms")
@@ -608,9 +653,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         want = query_answers(traffic, {(r.request, r.param) for r in requests}, tables)
         numbers, failed = judge_queries(mix, traffic, requests, hung, want)
     else:
-        env, verdicts = pipeline_reference(mix["pipeline"], tables)
+        answers, verdicts = pipeline_reference(mix["pipeline"], tables)
         numbers, failed = judge_runs(mix["pipeline"], requests, read_back,
-                                     head_log, env, verdicts)
+                                     head_log, answers, verdicts)
     limits = mix["limits"]
     correct = all(numbers[k] <= limits[k] for k in numbers)
 
@@ -669,18 +714,20 @@ def write_dump(path: Path, run: Measured) -> None:
 
 def _least_times(cell: Cell, traffic: Traffic, requests: List[Request], tables,
                  want, peak_rates) -> List[float]:
-    """Least device time of each request, from its statement and the rows
-    the scan handed over."""
+    """Least device time of each request, from its statement, the rows of
+    each of its tables that its own conjuncts keep, the rows that survive
+    its joins, and the rows of its answer."""
     if peak_rates is None:
         return []
     cost: Dict[Tuple[int, Optional[int]], float] = {}
     for key in {(r.request, r.param) for r in requests}:
-        stmt = traffic.statement(key[0])
-        table = tables[stmt["table"]]
-        dtypes = {c: a.dtype for c, a in table.items()}
-        rows_in = reference.selected_rows(stmt, tables, traffic.params(*key))
-        rows_out = len(next(iter(want[key].values()), ()))
-        ops, nbytes = work.statement_work(stmt, dtypes, rows_in, rows_out)
+        stmt, params = traffic.statement(key[0]), traffic.params(*key)
+        dtypes = {t: {c: a.dtype for c, a in tables[t].items()}
+                  for t in reference.statement_tables(stmt)}
+        ops, nbytes = work.statement_work(
+            stmt, dtypes, reference.table_rows(stmt, tables, params),
+            reference.selected_rows(stmt, tables, params),
+            reference.kept_rows(stmt, reference.rows(want[key])))
         cost[key] = work.least_time(ops, nbytes, peak_rates)
     return [cost[(r.request, r.param)] for r in requests]
 

@@ -47,9 +47,10 @@ def main() -> int:
             repro.Client(Path(tmp) / "lake") as client:
         traffic, _ = harness.prepare(cell, cell.generate(args.seed), args.seed,
                                      client, STARTED, log)
+        workers = harness.Workers(client, traffic, int(cell.mix["workers"]), False)
         for rate in args.rates:
             t_open, t_close, reqs, hung, late = harness._query_window(
-                client, traffic, int(cell.mix["workers"]), rate, args.seconds, False)
+                workers, rate, args.seconds)
             reqs.sort(key=lambda r: r.start)
             by_op = {}
             for op in sorted({r.op for r in reqs}):
@@ -67,6 +68,7 @@ def main() -> int:
                 "by_op": by_op, "drain_s": t_close - (t_open + args.seconds),
                 "sender_late_s": late,
             }), flush=True)
+        workers.close()
     return 0
 
 

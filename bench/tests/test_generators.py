@@ -1,5 +1,7 @@
 """The generators fix what each statement's scan hands to the device:
 two seeds give every statement of every mix the same row count."""
+import json
+
 import numpy as np
 import pytest
 
@@ -77,3 +79,14 @@ def test_lineitem_keys_follow_dbgen():
     assert np.all(np.diff(orderkey) >= 0) and set(np.unique(orderkey) % 32) <= set(range(1, 9))
     starts = np.flatnonzero(np.diff(orderkey)) + 1
     assert np.all(linenumber[starts] == 1)
+
+
+def test_a_configuration_states_its_own_tiny_sizes(tmp_path, monkeypatch):
+    config = tmp_path / "tpch_q3.json"
+    config.write_text(json.dumps({"scale_factor": 1, "tiny": {"scale_factor": 0.01}}))
+    spec = dict(tiny.SPEC, configs=tiny.SPEC["configs"] + [{"name": "tpch_q3",
+                                                             "file": str(config)}])
+    monkeypatch.setattr(tiny, "SPEC", spec)
+    assert tiny.sizes("tpch_q3") == {"scale_factor": 0.01}
+    assert tiny.sizes("tpch_sf1") == {"rows": 60_000}
+    assert tiny.CELLS == [w["name"] for w in spec["workloads"]]

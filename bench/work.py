@@ -3,13 +3,18 @@
 The least time a chip could take for one query is the larger of its
 operations over the chip's peak rate and its bytes over the chip's HBM
 bandwidth.  Both are counted from the structured statement and the rows
-that the scan handed to the device, not from the program that ran, so a
-change of implementation (kernel or jnp) is held to the same number.
+of each of its tables that its own conjuncts keep, as if every conjunct
+were pushed into the scan of the table it names; not from the program
+that ran, so a change of implementation (kernel or jnp, predicates
+pushed down or not) is held to the same number.
 
-* bytes: every column handed over, read once at its width, plus the
-  answer written once (keys at their width, aggregates at 4 bytes);
-* operations: per row handed over, one for each group key (its slot),
-  and for each aggregate the expression's arithmetic plus one add.
+* bytes: for each table, the rows its scan hands over times the width
+  of the columns it hands over (join keys included), each read once;
+  plus the answer written once (keys at their width, aggregates at 4
+  bytes);
+* operations: one for each join key of each row handed over; then, per
+  row that survives the joins, one for each group key (its slot), and
+  for each aggregate the expression's arithmetic plus one add.
 
 Peaks come from ``peaks.json``, keyed by JAX's ``device_kind``; a device
 that is not in the table is an error.
@@ -22,7 +27,7 @@ from typing import Dict, Mapping, Tuple
 
 import numpy as np
 
-from reference import expression_columns, expression_ops
+from reference import expression_ops, input_columns, join_keys
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 AGG_BYTES = 4
@@ -40,25 +45,35 @@ def peaks(device_kind: str, path: Path = PEAKS) -> Dict[str, float]:
 
 
 def handed_columns(stmt: Mapping) -> list:
-    """Columns the scan hands to the device (WHERE is applied on the host)."""
-    cols = list(stmt.get("group_by", ()))
-    cols += [src for _, src in stmt.get("select", ())]
-    for agg in stmt.get("aggs", ()):
-        if "expr" in agg:
-            cols += expression_columns(agg["expr"])
-    return list(dict.fromkeys(cols))
+    """Columns the scans hand to the device (WHERE is applied on the host):
+    the answer's inputs and the join keys."""
+    return list(dict.fromkeys(input_columns(stmt) + join_keys(stmt)))
 
 
-def statement_work(stmt: Mapping, dtypes: Mapping[str, np.dtype],
-                   rows_in: int, rows_out: int) -> Tuple[float, float]:
-    """``(operations, bytes)`` of one statement over ``rows_in`` rows."""
-    width = sum(np.dtype(dtypes[c]).itemsize for c in handed_columns(stmt))
+def statement_work(stmt: Mapping, dtypes: Mapping[str, Mapping[str, np.dtype]],
+                   scanned: Mapping[str, int], rows_in: int,
+                   rows_out: int) -> Tuple[float, float]:
+    """``(operations, bytes)`` of one statement.
+
+    ``dtypes`` gives the columns of each of the statement's tables,
+    ``scanned`` the rows of each that its own conjuncts keep, ``rows_in``
+    the rows that survive every conjunct and join (those the group keys
+    and aggregates see) and ``rows_out`` the rows of the answer."""
+    owner = {c: t for t, cols in dtypes.items() for c in cols}
+    width = {t: 0 for t in dtypes}
+    for c in handed_columns(stmt):
+        width[owner[c]] += np.dtype(dtypes[owner[c]][c]).itemsize
+    probes = {t: 0 for t in dtypes}
+    for c in join_keys(stmt):
+        probes[owner[c]] += 1
     aggs = stmt.get("aggs", ())
-    out_width = sum(np.dtype(dtypes[k]).itemsize for k in stmt.get("group_by", ()))
+    out_width = sum(np.dtype(dtypes[owner[k]][k]).itemsize for k in stmt.get("group_by", ()))
     out_width += AGG_BYTES * len(aggs)
     per_row = len(stmt.get("group_by", ())) + sum(
         (expression_ops(a["expr"]) if "expr" in a else 0) + 1 for a in aggs)
-    return float(rows_in * per_row), float(rows_in * width + rows_out * out_width)
+    ops = rows_in * per_row + sum(n * probes[t] for t, n in scanned.items())
+    nbytes = sum(n * width[t] for t, n in scanned.items()) + rows_out * out_width
+    return float(ops), float(nbytes)
 
 
 def least_time(ops: float, nbytes: float, peak: Mapping[str, float]) -> float:
