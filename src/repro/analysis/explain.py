@@ -122,6 +122,8 @@ class ExplainedQuery:
     grouping: Optional[str] = None
     #: filter conjuncts pushed into the FROM table's scan, rendered
     pushdown: Tuple[str, ...] = ()
+    #: the FROM table, whose scan alone takes pushed conjuncts
+    source: str = ""
     #: filter remainder the engine evaluates post-scan, rendered
     residual: Optional[str] = None
     #: table -> {columns, shards, pruned_shards, rows}
@@ -143,7 +145,7 @@ class ExplainedQuery:
                 f"({s['pruned_shards']} pruned), {s['rows']} row(s)"
             )
         for p in self.pushdown:
-            lines.append(f"    pushdown  {p} (into the scan)")
+            lines.append(f"    pushdown  {p} (into the scan of {self.source})")
         if self.residual:
             lines.append(f"    residual  {self.residual}")
         if self.error is not None:
@@ -258,6 +260,7 @@ def explain_query(
         pushdown=tuple(
             f"{p.column} {p.op} {p.value:g}" for p in pushed
         ),
+        source=query.source,
         residual=render_expr(residual) or None,
         scans=scans,
         output_schema=_schema_pairs(out_schema),

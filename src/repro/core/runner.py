@@ -254,7 +254,7 @@ class Runner:
             plan_interactive_query,
             resolve_query_snapshots,
         )
-        from repro.engine.exec import compile_query, group_path
+        from repro.engine.exec import compile_query, group_path, sorted_rows
         from repro.engine.sql import parse_sql
         from repro.table.scan import KERNEL_CHUNK_ROWS
 
@@ -304,15 +304,15 @@ class Runner:
             with phases("copy"):
                 jax.block_until_ready(rels)
             residual_query = _replace(query, filter_expr=residual)
-            joined = {j.table: rels[j.table] for j in query.joins}
+            source = rels[query.source]
+            joined = {j.table: rels[j.table] for j in query.joins} or None
             program = compile_query(residual_query, route=route)
             grouped_by = group_path(query, route)
             with phases("device", group_path=grouped_by):
-                out = jax.block_until_ready(
-                    program(rels[query.source], joined or None)
-                )
+                out, survived = jax.block_until_ready(program(source, joined))
             with phases("fetch"):
                 result = out.to_numpy()
+                rows_joined = 0 if survived is None else int(survived)
             exec_s = time.perf_counter() - t3
 
         rows_out = len(next(iter(result.values()))) if result else 0
@@ -333,6 +333,9 @@ class Runner:
             fetch_s=phases["fetch"],
             compiles=phases.compiles,
             query_id=query_id,
+            rows_handed=sum(r.capacity for r in rels.values()),
+            rows_joined=rows_joined,
+            sorted_rows=sorted_rows(program, source, joined),
         ))
         return result
 

@@ -28,6 +28,7 @@ the pure-jnp operators below, which remain the reference semantics.
 from __future__ import annotations
 
 import functools
+import threading
 from collections import Counter
 from dataclasses import replace
 from typing import Callable, Dict, List, Optional, Tuple
@@ -40,6 +41,21 @@ from repro.engine.columnar import Columnar
 from repro.engine.query import Agg, Query
 from repro.engine.route import RouteDecision, native_filter_of
 from repro.runtime.phases import named
+
+#: the sort sizes of the program being traced on this thread, if any
+_tracing = threading.local()
+
+
+def _argsort(x: jax.Array) -> jax.Array:
+    """Stable argsort of a 1-D array: every sort of the engine goes
+    through here, so that a program traced by :func:`compile_query`
+    counts the elements it sorts.  A sort of one element counts nothing:
+    XLA drops it."""
+    sizes = getattr(_tracing, "sizes", None)
+    if sizes is not None and x.shape[0] > 1:
+        sizes.append(x.shape[0])
+    return jnp.argsort(x, stable=True)
+
 
 def apply_filter(rel: Columnar, query: Query) -> Columnar:
     if query.filter_expr is None:
@@ -158,8 +174,8 @@ def _first_match_gather(join, jq, combined, valid, jrel, *, sql=None):
         return gathered, found
 
     rk32 = right_keys.astype(jnp.int32)
-    perm = jnp.argsort(rk32, stable=True)
-    perm = perm[jnp.argsort((~jrel.valid[perm]).astype(jnp.int32), stable=True)]
+    perm = _argsort(rk32)
+    perm = perm[_argsort((~jrel.valid[perm]).astype(jnp.int32))]
     sorted_valid = jrel.valid[perm]
     # invalid tail carries the max sentinel; a *valid* key equal to the
     # sentinel still wins because searchsorted("left") lands on it first
@@ -205,9 +221,9 @@ def _lex_sort_perm(rel: Columnar, keys) -> jax.Array:
         kcol = rel.column(k)
         if kcol.dtype.kind not in ("i", "u", "b"):
             raise TypeError(f"group key {k!r} must be integer/bool, got {kcol.dtype}")
-        order = jnp.argsort(kcol[perm].astype(jnp.int32), stable=True)
+        order = _argsort(kcol[perm].astype(jnp.int32))
         perm = perm[order]
-    order = jnp.argsort((~rel.valid[perm]).astype(jnp.int32), stable=True)
+    order = _argsort((~rel.valid[perm]).astype(jnp.int32))
     return perm[order]
 
 
@@ -363,7 +379,7 @@ def _present_first(present: jax.Array, slot_cols: Dict[str, jax.Array]) -> Colum
     """Slot-indexed group outputs in apply_groupby's layout: present
     groups first, in ascending slot order, absent slots zeroed — so the
     compacted result equals the sort path's."""
-    order = jnp.argsort((~present).astype(jnp.int32), stable=True)
+    order = _argsort((~present).astype(jnp.int32))
     present_s = present[order]
     out = {
         name: jnp.where(present_s, c[order], jnp.zeros((), c.dtype))
@@ -446,9 +462,9 @@ def apply_sort(rel: Columnar, query: Query) -> Columnar:
         vals = rel.column(column)[perm]
         if vals.dtype.kind == "b":
             vals = vals.astype(jnp.int32)
-        order = jnp.argsort(-vals if desc else vals, stable=True)
+        order = _argsort(-vals if desc else vals)
         perm = perm[order]
-    order = jnp.argsort((~rel.valid[perm]).astype(jnp.int32), stable=True)
+    order = _argsort((~rel.valid[perm]).astype(jnp.int32))
     perm = perm[order]
     return Columnar({k: v[perm] for k, v in rel.columns.items()}, rel.valid[perm])
 
@@ -476,29 +492,48 @@ def execute_query(
     route with a ``group_domain`` groups without a sort, anything else
     (including no route at all) runs the reference jnp operators.
     """
-    rel, display = _combined_relation(query, rel, joined)
-    if route is not None and route.engine_path == "kernel" and query.is_aggregation:
-        rel = _kernel_filter_agg(rel, query, route)
+    return _execute(query, rel, group_capacity, joined, route)[0]
+
+
+def _execute(
+    query: Query,
+    rel: Columnar,
+    group_capacity: Optional[int],
+    joined: Optional[Dict[str, Columnar]],
+    route: Optional[RouteDecision],
+) -> Tuple[Columnar, Optional[jax.Array]]:
+    """:func:`execute_query`'s answer, and for a statement with joins the
+    number of rows that survive them and the residual filter (``None``
+    without joins).  Each operator runs under a ``repro.*`` named scope,
+    which names its operations in the program's metadata only."""
+    with jax.named_scope("repro.join"):
+        rel, display = _combined_relation(query, rel, joined)
+    kernel = route is not None and route.engine_path == "kernel" and query.is_aggregation
+    if not kernel:
+        with jax.named_scope("repro.filter"):
+            rel = apply_filter(rel, query)
+    joined_rows = None
+    if query.joins:  # the kernel filters inside its own pass
+        joined_rows = (apply_filter(rel, query) if kernel else rel).num_valid()
+    if query.is_aggregation:
+        with jax.named_scope("repro.group"):
+            if kernel:
+                rel = _kernel_filter_agg(rel, query, route)
+            else:
+                grel, gquery = _normalize_group_keys(rel, query)
+                if route is not None and route.group_domain is not None:
+                    rel = _dense_groupby(grel, gquery, route)
+                else:
+                    rel = apply_groupby(grel, gquery, capacity=group_capacity)
         if query.projections:
             rel = apply_projection(rel, query)
-    else:
-        rel = apply_filter(rel, query)
-        if query.is_aggregation:
-            grel, gquery = _normalize_group_keys(rel, query)
-            if route is not None and route.group_domain is not None:
-                rel = _dense_groupby(grel, gquery, route)
-            else:
-                rel = apply_groupby(grel, gquery, capacity=group_capacity)
-            if query.projections:
-                rel = apply_projection(rel, query)
-        else:
-            if query.projections:
-                rel = apply_projection(rel, query)
-            elif display is not None:
-                rel = rel.select(display)  # SELECT * over joined sources
-    rel = apply_sort(rel, query)
-    rel = apply_limit(rel, query)
-    return rel
+    elif query.projections:
+        rel = apply_projection(rel, query)
+    elif display is not None:
+        rel = rel.select(display)  # SELECT * over joined sources
+    with jax.named_scope("repro.order"):
+        rel = apply_limit(apply_sort(rel, query), query)
+    return rel, joined_rows
 
 
 def group_path(query: Query, route: Optional[RouteDecision]) -> str:
@@ -528,18 +563,28 @@ def program_name(query: Query) -> str:
     return "_".join(parts)
 
 
+def _capacities(rel: Columnar, joined: Optional[Dict[str, Columnar]]) -> Tuple[int, ...]:
+    return (rel.capacity, *(joined[t].capacity for t in sorted(joined or {})))
+
+
 @functools.lru_cache(maxsize=512)
 def _compiled_for(
     query: Query, group_capacity: Optional[int], route: Optional[RouteDecision]
 ) -> Callable:
     def run(
         rel: Columnar, joined: Optional[Dict[str, Columnar]] = None
-    ) -> Columnar:
-        return execute_query(
-            query, rel, group_capacity=group_capacity, joined=joined, route=route
-        )
+    ) -> Tuple[Columnar, Optional[jax.Array]]:
+        outer, _tracing.sizes = getattr(_tracing, "sizes", None), []
+        try:
+            out = _execute(query, rel, group_capacity, joined, route)
+            program.sorted_rows[_capacities(rel, joined)] = sum(_tracing.sizes)
+        finally:
+            _tracing.sizes = outer
+        return out
 
-    return jax.jit(named(run, program_name(query)))
+    program = jax.jit(named(run, program_name(query)))
+    program.sorted_rows = {}
+    return program
 
 
 def compile_query(
@@ -547,10 +592,21 @@ def compile_query(
     *,
     group_capacity: Optional[int] = None,
     route: Optional[RouteDecision] = None,
-) -> Callable[..., Columnar]:
+) -> Callable[..., Tuple[Columnar, Optional[jax.Array]]]:
     """Return the jit-compiled executable for a query (cached — this cache
     is the engine-level face of the runtime's warm-container cache).
 
-    The executable takes ``(rel, joined=None)``; being a ``jax.jit``
-    function, its ``lower(...)`` gives the program it runs."""
+    The executable takes ``(rel, joined=None)`` and returns the answer and,
+    for a statement with joins, the rows that survived them on the device
+    (:func:`_execute`); being a ``jax.jit`` function, its ``lower(...)``
+    gives the program it runs.  :func:`sorted_rows` says how much it sorts."""
     return _compiled_for(query, group_capacity, route)
+
+
+def sorted_rows(
+    program: Callable, rel: Columnar, joined: Optional[Dict[str, Columnar]] = None
+) -> int:
+    """Elements that ``program`` (from :func:`compile_query`) sorts in one
+    call on relations of these capacities, summed over its sort sites:
+    counted when it traced them, so every call reports it."""
+    return program.sorted_rows[_capacities(rel, joined)]

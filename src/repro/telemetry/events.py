@@ -284,7 +284,15 @@ class QueryExecuted(Event):
     of the compiled program until its outputs are ready), ``fetch_s``
     (its outputs back to the host).  ``compiles`` counts the programs
     the call handed to XLA; ``query_id`` is process-unique and tags the
-    query's ``ScanShardRead`` events."""
+    query's ``ScanShardRead`` events.
+
+    Counters of the work handed to the device: ``rows_handed`` (the rows
+    the statement's scans hand over, summed over its tables, after the
+    conjuncts pushed into them), ``rows_joined`` (for a statement with
+    joins, the valid rows after the joins and the residual filter, a
+    scalar the program returns beside its answer; 0 without joins) and
+    ``sorted_rows`` (the elements the compiled program sorts in one call,
+    summed over its sort sites, counted when it was traced)."""
 
     kind: ClassVar[str] = "QueryExecuted"
     table: str = ""
@@ -303,6 +311,9 @@ class QueryExecuted(Event):
     fetch_s: float = 0.0
     compiles: int = 0
     query_id: int = 0
+    rows_handed: int = 0
+    rows_joined: int = 0
+    sorted_rows: int = 0
 
 
 # ------------------------------------------------------------ maintenance

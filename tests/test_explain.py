@@ -401,6 +401,40 @@ def client(tmp_path, rng):
         yield c
 
 
+def test_explain_names_the_table_a_conjunct_is_pushed_into(tmp_path, rng):
+    """TPC-H Q3: only lineitem, the FROM table, takes its conjunct into
+    its scan; orders and customer are read whole, their conjuncts left
+    in the residual for the device."""
+    n = 300
+    with repro.Client(tmp_path / "lake") as c:
+        c.write_table("customer", {
+            "c_custkey": np.arange(1, 31, dtype=np.int32),
+            "c_mktsegment": rng.integers(0, 5, 30).astype(np.int8)})
+        c.write_table("orders", {
+            "o_orderkey": np.arange(1, 101, dtype=np.int32),
+            "o_custkey": rng.integers(1, 31, 100).astype(np.int32),
+            "o_orderdate": rng.integers(8000, 10000, 100).astype(np.int32),
+            "o_shippriority": np.zeros(100, np.int32)})
+        c.write_table("lineitem", {
+            "l_orderkey": rng.integers(1, 101, n).astype(np.int32),
+            "l_extendedprice": rng.random(n).astype(np.float32),
+            "l_discount": rng.random(n).astype(np.float32),
+            "l_shipdate": rng.integers(8000, 10000, n).astype(np.int32)})
+        ex = c.explain(
+            "SELECT l_orderkey, SUM(l_extendedprice * (1 - l_discount)) AS revenue, "
+            "o_orderdate, o_shippriority FROM lineitem "
+            "JOIN orders ON l_orderkey = o_orderkey JOIN customer ON o_custkey = c_custkey "
+            "WHERE c_mktsegment = 1 AND o_orderdate < '1995-03-15' "
+            "AND l_shipdate > '1995-03-15' "
+            "GROUP BY l_orderkey, o_orderdate, o_shippriority "
+            "ORDER BY revenue DESC, o_orderdate LIMIT 10")
+    text = ex.describe()
+    pushed = [line.strip() for line in text.splitlines() if "pushdown" in line]
+    assert pushed == ["pushdown  l_shipdate > 9204 (into the scan of lineitem)"]
+    assert "c_mktsegment" in ex.residual and "o_orderdate" in ex.residual
+    assert "(into the scan)" not in text
+
+
 def test_explain_sql_kernel_verdict_with_plan(client):
     ex = client.explain(
         "SELECT country, SUM(amount) AS rev FROM orders "

@@ -180,10 +180,13 @@ def test_join_matches_oracle(rng, how):
         "SELECT t.zone, t.fare, z.borough FROM trips AS t "
         f"{kw} zones AS z ON t.zone = z.zone_id"
     )
-    out = compile_query(parse_sql(sql))(
+    answer, joined_rows = compile_query(parse_sql(sql))(
         Columnar.from_numpy(trips), {"zones": Columnar.from_numpy(zones)}
-    ).to_numpy()
+    )
+    out = answer.to_numpy()
     want = _join_oracle(trips, zones, how)
+    # a left join keeps every row; an inner one the rows that matched
+    assert int(joined_rows) == len(want["zone"])
     np.testing.assert_array_equal(out["zone"], want["zone"])
     np.testing.assert_array_equal(out["fare"], want["fare"])
     np.testing.assert_array_equal(out["borough"], want["borough"])
